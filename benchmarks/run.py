@@ -1,113 +1,43 @@
 """Benchmark harness — one section per paper table.
 
   Table 1 (paper §5.1): memory-copy engine variants (VMEM tilings vs
-          stock copy).  The stock path is the XLA:CPU fused copy; the
+          stock copy).  The stock path is a bare XLA copy; the
           Pallas variants are characterized structurally (working-set
           bytes — interpret-mode wall-clock is not hardware-indicative;
           correctness is covered in tests/test_kernels.py).
   Table 2 (§5.2): put/get latency/bandwidth through the full POSH layer
-          vs a local device copy — 8 fake PEs in a subprocess.
+          vs a local device copy.
   Table 3 (§5.3): POSH collectives vs native XLA collectives (the
           Berkeley-UPC/GASNet role), incl. the compile-time
           algorithm-selection comparison (§4.5.4).
+
+Every table runs in ONE worker process (``benchmarks/_worker.py``) over
+``jax.devices()``: 8 fake CPU PEs off-chip, the attached chips on a TPU
+host.  This parent never imports jax, so the worker can own the chip.
 
 Prints ``table,name,elems,us_per_call,derived`` CSV lines.
 """
 import os
 import subprocess
 import sys
-import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, os.path.join(ROOT, "src"))
 
 
-def bench_copy_variants():
-    import jax
-    import jax.numpy as jnp
-
-    from repro.kernels import ops, symm_copy
-
-    print("table,op,elems,us_per_call,derived_gbps_or_vmem_kib")
-    for elems in [4096, 262144, 4194304]:
-        x = jnp.arange(elems, dtype=jnp.float32)
-        fn = jax.jit(lambda v: ops.symm_copy(v, "stock"))
-        for _ in range(3):
-            jax.block_until_ready(fn(x))
-        t0 = time.perf_counter()
-        for _ in range(20):
-            out = fn(x)
-        jax.block_until_ready(out)
-        dt = (time.perf_counter() - t0) / 20
-        print(f"table1,copy_stock,{elems},{dt*1e6:.2f},"
-              f"{elems*4/dt/1e9:.3f}")
-        for variant in symm_copy.VARIANTS:
-            kib = symm_copy.vmem_bytes(variant) / 1024
-            print(f"table1,copy_{variant},{elems},nan,{kib:.0f}")
-
-
-def bench_multipe():
+def main() -> None:
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    env.pop("XLA_FLAGS", None)
     r = subprocess.run(
         [sys.executable, os.path.join(ROOT, "benchmarks", "_worker.py")],
         capture_output=True, text=True, env=env, timeout=3600)
     if r.returncode != 0 or "WORKER_DONE" not in r.stdout:
-        print("multipe worker FAILED", file=sys.stderr)
+        print("benchmark worker FAILED", file=sys.stderr)
         print(r.stdout[-4000:], file=sys.stderr)
         print(r.stderr[-4000:], file=sys.stderr)
         raise SystemExit(1)
     for line in r.stdout.splitlines():
         if line and not line.startswith("WORKER_DONE"):
             print(line)
-
-
-def bench_train_throughput():
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-
-    from repro import compat, configs
-    from repro.data import SyntheticLM
-    from repro.models import registry
-    from repro.parallel.ctx import ParallelCtx, smap
-    from repro.train.optimizer import AdamWConfig, adamw_init
-    from repro.train.step import make_train_step, train_state_specs
-
-    ctx = ParallelCtx(dp_size=1, tp_size=1, sp=False, remat=True,
-                      param_dtype=jnp.float32, compute_dtype=jnp.float32)
-    cfg = configs.get_smoke("qwen3-8b")
-    api = registry.build(cfg)
-    opt = AdamWConfig(lr=1e-3)
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
-    sspecs = train_state_specs(cfg, ctx, api, opt)
-    params = api.init(jax.random.PRNGKey(0), cfg, ctx)
-    opt_state = smap(lambda p: adamw_init(p, ctx, opt), mesh,
-                     (api.specs(cfg, ctx),), sspecs["opt"])(params)
-    state = {"params": params, "opt": opt_state,
-             "step": jnp.zeros((), jnp.int32)}
-    fn = jax.jit(smap(make_train_step(cfg, ctx, api, opt), mesh,
-                      (sspecs, {"tokens": P("data")}),
-                      (sspecs, {"loss": P(), "grad_norm": P(),
-                                "step": P()})))
-    data = SyntheticLM(vocab=cfg.vocab, seq_len=cfg.max_seq, global_batch=8)
-    state, m = fn(state, data.batch(0))
-    jax.block_until_ready(m["loss"])
-    t0 = time.perf_counter()
-    steps = 5
-    for s in range(1, steps + 1):
-        state, m = fn(state, data.batch(s))
-    jax.block_until_ready(m["loss"])
-    dt = (time.perf_counter() - t0) / steps
-    toks = 8 * cfg.max_seq
-    print(f"train,smoke_step,{toks},{dt*1e6:.0f},{toks/dt:.0f}")
-
-
-def main() -> None:
-    bench_copy_variants()
-    bench_multipe()
-    bench_train_throughput()
 
 
 if __name__ == "__main__":

@@ -144,7 +144,7 @@ def run_case(case, arch, backend, attn_impl, page_tokens, n_pages,
     # object ids, so stale per-queue checker state could alias onto
     # this case's freshly-built pool/mailbox queues
     shmemcheck.reset()
-    eng, cfg = build_engine(arch, backend=backend,
+    eng, cfg = build_engine(arch, smoke=True, backend=backend,
                             page_tokens=page_tokens, n_pages=n_pages,
                             max_batch=max_batch, attn_impl=attn_impl,
                             prefill_chunk=prefill_chunk,

@@ -1,31 +1,18 @@
-"""repro.compat — version-portable wrappers over the handful of jax
-APIs that moved between jax 0.4.x and 0.5+/0.6+.
+"""repro.compat — the handful of jax mesh/shard_map calls the repo makes,
+in one place.
 
-The framework is written against the modern surface (``jax.shard_map``,
-``jax.make_mesh(..., axis_types=...)``, ``jax.lax.axis_size``); this
-module backfills those names on older installs so one codebase runs on
-both.  Everything else in the repo imports from here instead of
-hand-rolling try/except at each call site.
-
-    make_mesh(shape, names)      jax.make_mesh, dropping axis_types when
-                                 the install predates them
-    shard_map(fn, mesh, ...)     jax.shard_map | experimental shard_map
-                                 (check_vma= maps onto check_rep=)
+    make_mesh(shape, names)      jax.make_mesh with Auto axis types
+    shard_map(fn, mesh, ...)     jax.shard_map
     axis_size(axis) -> int       static team size inside shard_map
-    axis_index(axis)             traced rank (stable, re-exported for
-                                 symmetry)
+    axis_index(axis)             traced rank (re-exported for symmetry)
 """
 from __future__ import annotations
 
-from typing import Optional, Sequence, Union
+from typing import Sequence, Union
 
 import jax
 
 Axis = Union[str, Sequence[str]]
-
-_HAS_NATIVE_SHARD_MAP = hasattr(jax, "shard_map")
-_HAS_AXIS_TYPES = hasattr(jax.sharding, "AxisType")
-_HAS_AXIS_SIZE = hasattr(jax.lax, "axis_size")
 
 
 def _canon(axis: Axis):
@@ -34,38 +21,26 @@ def _canon(axis: Axis):
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               devices=None, explicit: bool = False) -> "jax.sharding.Mesh":
-    """``jax.make_mesh`` with Auto axis types when the install supports
-    them (newer jax defaults to Explicit, which breaks shard_map-with-
-    manual-collectives code written for Auto)."""
-    kw = {}
+    """``jax.make_mesh`` with Auto axis types (jax defaults to Explicit,
+    which breaks shard_map-with-manual-collectives code written for
+    Auto)."""
+    at = (jax.sharding.AxisType.Explicit if explicit
+          else jax.sharding.AxisType.Auto)
+    kw = {"axis_types": (at,) * len(axis_names)}
     if devices is not None:
         kw["devices"] = devices
-    if _HAS_AXIS_TYPES:
-        at = (jax.sharding.AxisType.Explicit if explicit
-              else jax.sharding.AxisType.Auto)
-        kw["axis_types"] = (at,) * len(axis_names)
     return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kw)
 
 
 def shard_map(fn, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` when available, else the 0.4.x experimental one
-    (whose replication checker is called ``check_rep``)."""
-    if _HAS_NATIVE_SHARD_MAP:
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def axis_size(axis: Axis) -> int:
     """Static size of a (possibly multi-) mesh axis, callable inside
-    shard_map at trace time.  On old jax ``lax.axis_size`` does not
-    exist; ``psum(1, axis)`` constant-folds to the same static int."""
-    ax = _canon(axis)
-    if _HAS_AXIS_SIZE:
-        return int(jax.lax.axis_size(ax))
-    return int(jax.lax.psum(1, ax))
+    shard_map at trace time."""
+    return int(jax.lax.axis_size(_canon(axis)))
 
 
 def axis_index(axis: Axis):

@@ -1,7 +1,7 @@
 """Serving CLI: continuous batching over the paged symmetric-heap KV
 cache with seeded synthetic traffic.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch qwen3-8b \\
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen3-8b --smoke \\
         --requests 16 --rate 8 --page-tokens 8 \\
         --temperature 0.8 --top-p 0.9
 
@@ -16,10 +16,10 @@ arch as a small draft model) without changing a single output token:
 acceptance is exact matching against the engine's counter-RNG draws,
 so speculation only shrinks tick counts.  Prints per-request decode
 traces
-when --trace is set, then the throughput/latency summary.  Smoke-size
-configs run on CPU; the same driver scales to a TPU mesh by
-constructing the ctx from ``launch.mesh.make_ctx`` and tensor-parallel
-step functions (see tests/multipe/run_serve.py for the mesh wiring).
+when --trace is set, then the throughput/latency summary.  ``--smoke``
+configs (f32) run on CPU; without it the published config is served in
+bf16 on one device.  Tensor-parallel serving over a mesh goes through
+``serve.MeshExec`` (``chip_smoke.py --four-chips``).
 """
 from __future__ import annotations
 
@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import configs, serve
+from repro.launch.cache import enable_compile_cache
 from repro.models import registry
 from repro.parallel.ctx import ParallelCtx
 
@@ -63,23 +64,28 @@ def parse_disagg(spec: str) -> tuple[int, int]:
     return n_prefill, n_decode
 
 
-def build_engine(arch: str, *, backend: str = "xla", page_tokens: int = 8,
-                 n_pages: int = 64, max_batch: int = 4,
-                 attn_impl: str = "ref", prefix_keep: bool = False,
-                 prefill_chunk: int = 8, tick_tokens: int = 0,
-                 sample_seed: int = 0, seed: int = 0, spec_k: int = 0,
-                 draft: str = "ngram", disagg: str = "",
+def build_engine(arch: str, *, smoke: bool = False, backend: str = "xla",
+                 page_tokens: int = 8, n_pages: int = 64,
+                 max_batch: int = 4, attn_impl: str = "ref",
+                 prefix_keep: bool = False, prefill_chunk: int = 8,
+                 tick_tokens: int = 0, sample_seed: int = 0, seed: int = 0,
+                 spec_k: int = 0, draft: str = "ngram", disagg: str = "",
                  router: str = "host", slo=None):
-    cfg = configs.get_smoke(arch)
+    """One-device engine for ``arch``: its published config served in
+    bf16 (params, compute and KV pages), or with ``smoke=True`` the
+    reduced config in f32."""
+    get = configs.get_smoke if smoke else configs.get
+    dtype = jnp.float32 if smoke else jnp.bfloat16
+    cfg = get(arch)
     ctx = ParallelCtx(dp_size=1, tp_size=1, sp=False, remat=False,
-                      backend=backend, param_dtype=jnp.float32,
-                      compute_dtype=jnp.float32)
+                      backend=backend, param_dtype=dtype,
+                      compute_dtype=dtype)
     api = registry.build(cfg)
     params = api.init(jax.random.PRNGKey(seed), cfg, ctx)
     scfg = serve.ServeConfig(
         page_tokens=page_tokens, n_pages=n_pages, max_batch=max_batch,
         max_seq=cfg.max_seq, prefill_chunk=prefill_chunk,
-        tick_tokens=tick_tokens, attn_impl=attn_impl,
+        tick_tokens=tick_tokens, attn_impl=attn_impl, kv_dtype=dtype,
         prefix_keep=prefix_keep, sample_seed=sample_seed,
         # scfg.draft only names parameterless proposers; a draft ARCH
         # becomes an explicit DraftModelProposer below
@@ -100,8 +106,8 @@ def build_engine(arch: str, *, backend: str = "xla", page_tokens: int = 8,
         kv = serve.PagedKVCache(
             SymmetricHeap(("data",)), n_layers=cfg.n_layers,
             kv_heads=cfg.kv_per_rank(1), head_dim=cfg.head_dim,
-            n_pages=n_pages, page_tokens=page_tokens)
-        dcfg = configs.get_smoke(draft)
+            n_pages=n_pages, page_tokens=page_tokens, dtype=dtype)
+        dcfg = get(draft)
         dparams = registry.build(dcfg).init(
             jax.random.PRNGKey(seed + 1), dcfg, ctx)
         proposer = serve.DraftModelProposer(dparams, dcfg, ctx, scfg, kv,
@@ -121,6 +127,9 @@ def build_engine(arch: str, *, backend: str = "xla", page_tokens: int = 8,
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-8b")
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the reduced-config variant in f32 "
+                         "(default: the published config in bf16)")
     ap.add_argument("--backend", default="xla",
                     help="communicator backend (xla | posh | pallas)")
     ap.add_argument("--requests", type=int, default=16)
@@ -194,6 +203,7 @@ def main():
     ap.add_argument("--trace", action="store_true",
                     help="print the per-request decode trace")
     args = ap.parse_args()
+    enable_compile_cache()
 
     slo_cfg, slo_tkw = None, {}
     if args.slo:
@@ -212,7 +222,8 @@ def main():
                        n_tenants=args.tenants)
 
     eng, cfg = build_engine(
-        args.arch, backend=args.backend, page_tokens=args.page_tokens,
+        args.arch, smoke=args.smoke, backend=args.backend,
+        page_tokens=args.page_tokens,
         n_pages=args.n_pages, max_batch=args.max_batch,
         attn_impl=args.attn_impl, prefill_chunk=args.prefill_chunk,
         tick_tokens=args.tick_tokens, sample_seed=args.sample_seed,

@@ -21,6 +21,7 @@ from repro import compat, configs
 from repro.ckpt import Checkpointer
 from repro.data import SyntheticLM, batch_specs
 from repro.ft import StragglerPolicy
+from repro.launch.cache import enable_compile_cache
 from repro.models import registry
 from repro.parallel.ctx import ParallelCtx, smap
 from repro.train.optimizer import AdamWConfig, adamw_init
@@ -58,6 +59,7 @@ def main():
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--lr", type=float, default=3e-4)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = configs.get_smoke(args.arch) if args.smoke \
         else configs.get(args.arch)

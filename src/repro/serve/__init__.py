@@ -32,6 +32,7 @@ from .disagg import (CellRouter, CellSpec, DisaggEngine, HandoffTicket,
 from .engine import LocalExec, ServeConfig, ServeEngine, make_decode_step, \
     make_prefill, make_verify
 from .kv_cache import NULL_PAGE, PagedKVCache, PageMigration
+from .mesh_exec import MeshExec, init_sharded_params
 from .page_pool import SymmetricPagePool
 from .sampling import (GREEDY, SamplingParams, batch_state,
                        sample_from_candidates, sample_tokens,
@@ -43,7 +44,8 @@ from .spec import (DraftModelProposer, FixedProposer, NgramProposer,
 from .traffic import TrafficConfig, make_requests
 
 __all__ = [
-    "ServeConfig", "ServeEngine", "LocalExec",
+    "ServeConfig", "ServeEngine", "LocalExec", "MeshExec",
+    "init_sharded_params",
     "DisaggEngine", "CellRouter", "AmoCellRouter", "CellSpec",
     "HandoffTicket", "make_cells",
     "make_decode_step", "make_prefill", "make_verify",

@@ -31,9 +31,9 @@ The engine is split in two layers:
     (migrate -> chunk-prefill -> decode/verify), and drains every tick's
     planned page migrations with ``put_nbi`` + ONE ``quiet()`` on a
     ``CommQueue`` before the step functions run.  The execution
-    substrate is pluggable (``LocalExec`` jits on one device; the mesh
-    suite supplies a shard_map-wrapped equivalent), so the same
-    scheduler drives a single CPU process and an 8-PE TP mesh.
+    substrate is pluggable (``LocalExec`` jits on one device;
+    ``serve.MeshExec`` shard_maps the same steps over a DP x TP mesh),
+    so the same scheduler drives one device and a TP mesh.
 
 Batch slots are fixed (``ServeConfig.max_batch``): empty slots carry
 the null page table and length 0, which zeroes their attention output

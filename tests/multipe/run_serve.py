@@ -61,114 +61,13 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro import compat, configs, serve
-from repro.core import CommQueue, SymmetricHeap
-from repro.core.ordering import PermuteTransport
+from repro.core import SymmetricHeap
 from repro.models import embed as emb
 from repro.models import registry
 from repro.parallel.ctx import ParallelCtx, smap
 
 DP, TP = 2, 4
 mesh = compat.make_mesh((DP, TP), ("data", "model"))
-POOL_SPEC = P("data", "model")
-
-
-class MeshExec:
-    """ServeEngine execution substrate over the (data, model) mesh.
-    The pool rides with leading (dp, tp) axes so shard_map hands each
-    PE its own (rank-varying) page shard; host-visible tokens are
-    replicated."""
-
-    def __init__(self, params, pspecs, cfg, ctx, scfg, kv, my_pe=0):
-        self.params, self.kv = params, kv
-        self.my_pe = int(my_pe)       # which replica this cell reads
-        pf = serve.make_prefill(cfg, ctx, scfg)
-        dc = serve.make_decode_step(cfg, ctx, scfg)
-        vf = serve.make_verify(cfg, ctx, scfg)
-
-        # tokens are replica-varying once pages migrate (replica 1 may
-        # hold pages replica 0 does not), so they come back stacked per
-        # replica — the host reads its own cell's row
-        def pf_w(params, pool, ids, start, n_tok, bt, samp):
-            toks, kvo = pf(params, pool[0, 0], ids, start, n_tok, bt,
-                           samp)
-            return toks, kvo[None, None]
-
-        def dc_w(params, pool, toks, pos, bt, lens, samp):
-            nxt, kvo = dc(params, pool[0, 0], toks, pos, bt, lens, samp)
-            return nxt, kvo[None, None]
-
-        def vf_w(params, pool, ids, start, n_tok, bt, samp):
-            toks, kvo = vf(params, pool[0, 0], ids, start, n_tok, bt,
-                           samp)
-            return toks, kvo[None, None]
-
-        self._prefill = jax.jit(smap(
-            pf_w, mesh, (pspecs, POOL_SPEC, P(), P(), P(), P(), P()),
-            (P("data"), POOL_SPEC)))
-        self._decode = jax.jit(smap(
-            dc_w, mesh, (pspecs, POOL_SPEC, P(), P(), P(), P(), P()),
-            (P("data"), POOL_SPEC)))
-        self._verify = jax.jit(smap(
-            vf_w, mesh, (pspecs, POOL_SPEC, P(), P(), P(), P(), P()),
-            (P("data"), POOL_SPEC)))
-        self._migrate_cache = {}
-
-    def _my_row(self, toks):
-        # (DP*b,) token vectors and (DP*b, C) verify windows alike
-        t = np.asarray(toks)
-        return t.reshape((DP, -1) + t.shape[1:])[self.my_pe]
-
-    def init_pool(self):
-        return jnp.zeros((DP, TP) + self.kv.handle.shape,
-                         self.kv.handle.dtype)
-
-    def prefill(self, pool, ids, start, n_tok, bt, samp):
-        toks, pool = self._prefill(self.params, pool, jnp.asarray(ids),
-                                   jnp.asarray(start),
-                                   jnp.asarray(n_tok), jnp.asarray(bt),
-                                   samp)
-        return self._my_row(toks), pool
-
-    def decode(self, pool, tokens, pos, bt, lens, samp):
-        toks, pool = self._decode(self.params, pool,
-                                  jnp.asarray(tokens), jnp.asarray(pos),
-                                  jnp.asarray(bt), jnp.asarray(lens),
-                                  samp)
-        return self._my_row(toks), pool
-
-    def verify(self, pool, ids, start, n_tok, bt, samp):
-        toks, pool = self._verify(self.params, pool, jnp.asarray(ids),
-                                  jnp.asarray(start),
-                                  jnp.asarray(n_tok), jnp.asarray(bt),
-                                  samp)
-        return self._my_row(toks), pool
-
-    def set_params(self, params) -> None:
-        # weight hot-swap flip: the smap-wrapped step functions take
-        # params as an explicit argument, so the next tick's forwards
-        # run the new generation with no re-trace (same as LocalExec)
-        self.params = params
-
-    def migrate(self, pool, migrations):
-        migs = tuple(migrations)
-        if migs not in self._migrate_cache:
-            kv, name = self.kv, self.kv.handle.name
-
-            def mg(pool):
-                local = pool[0, 0]
-                q = CommQueue(("data", "model"), {name: local},
-                              transport=PermuteTransport())
-                st = kv.issue_migrations(
-                    q, local, migs,
-                    pairs_of=lambda m: [(m.src_pe * TP + t,
-                                         m.dst_pe * TP + t)
-                                        for t in range(TP)])
-                assert q.stats()["quiets"] == 1
-                return st[name][None, None]
-
-            self._migrate_cache[migs] = jax.jit(
-                smap(mg, mesh, (POOL_SPEC,), POOL_SPEC))
-        return self._migrate_cache[migs](pool)
 
 
 def build(backend, *, prefix_keep=False, my_pe=0, kv=None, scfg=None,
@@ -194,8 +93,8 @@ def build(backend, *, prefix_keep=False, my_pe=0, kv=None, scfg=None,
             heap, n_layers=cfg.n_layers,
             kv_heads=cfg.kv_per_rank(TP), head_dim=cfg.head_dim,
             n_pages=scfg.n_pages, page_tokens=scfg.page_tokens)
-    exec_ = MeshExec(params, api.specs(cfg, ctx), cfg, ctx, scfg, kv,
-                     my_pe=my_pe)
+    exec_ = serve.MeshExec(params, api.specs(cfg, ctx), cfg, ctx, scfg, kv,
+                           mesh, my_pe=my_pe)
     eng = serve.ServeEngine(params, cfg, ctx, scfg, kv=kv, exec_=exec_,
                             proposer=proposer, my_pe=my_pe)
     return eng, cfg

@@ -293,22 +293,22 @@ def test_disagg_cli_spec_and_builder():
     for bad in ("2", "0+2", "2+0", "a+b"):
         with pytest.raises(SystemExit):
             parse_disagg(bad)
-    eng, cfg = build_engine("qwen3-8b", n_pages=32, max_batch=2,
+    eng, cfg = build_engine("qwen3-8b", smoke=True, n_pages=32, max_batch=2,
                             disagg="1+1")
     assert isinstance(eng, DisaggEngine)
     assert [c.role for c in eng.cells] == ["prefill", "decode"]
     # --router wiring: amo builds the lock-free control plane
-    eng, _ = build_engine("qwen3-8b", n_pages=32, max_batch=2,
+    eng, _ = build_engine("qwen3-8b", smoke=True, n_pages=32, max_batch=2,
                           disagg="1+1", router="amo")
     assert eng.router_mode == "amo"
     assert isinstance(eng.router, serve.AmoCellRouter)
     assert len(eng.pools) == len(eng.engines)
-    eng, _ = build_engine("qwen3-8b", n_pages=32, max_batch=2,
+    eng, _ = build_engine("qwen3-8b", smoke=True, n_pages=32, max_batch=2,
                           router="amo")          # colocated: pool only
     assert isinstance(eng, ServeEngine)
     assert isinstance(eng.kv._pool, serve.SymmetricPagePool)
     with pytest.raises(SystemExit):
-        build_engine("qwen3-8b", router="bogus")
+        build_engine("qwen3-8b", smoke=True, router="bogus")
 
 
 # ======================================================================
