@@ -21,6 +21,11 @@ DEFAULT_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 def enable_compile_cache() -> str:
     """Turn the persistent compilation cache on; return its directory."""
+    # the step programs' ``jax.named_scope``s live in op metadata, which
+    # the key leaves out by default: a cache shared with a build whose
+    # programs differ only in their scopes would hand back that build's
+    # executables, and a profile of this one would name their ops
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get(ENV_VAR)
     if env:
         return env

@@ -64,6 +64,7 @@ from . import sampling
 from .kv_cache import NULL_PAGE, PagedKVCache
 from .scheduler import FCFSScheduler, Request
 from .slo import PRIORITIES, SLOConfig, SLOPolicy
+from .trace import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -148,39 +149,50 @@ def make_decode_step(cfg, ctx: ParallelCtx, scfg: ServeConfig):
 
     def step(params, pool, tokens, pos, bt, lens, samp):
         cd = ctx.compute_dtype
-        x = emb.embed_lookup(params["embed"], tokens[:, None], ctx)[:, 0]
+        with jax.named_scope("embed"):
+            x = emb.embed_lookup(params["embed"], tokens[:, None],
+                                 ctx)[:, 0]
         b = x.shape[0]
 
         def body(carry, inputs):
             x, pool = carry
             p, li = inputs
-            h = norm_apply("rms", p["ln1"], x).astype(cd)
-            q, k, v = attn.project_qkv(p["attn"], h[:, None],
-                                       pos[:, None], cfg, ctx)
-            q, k, v = q[:, 0], k[:, 0], v[:, 0]
-            pool = _write_pages(pool, li, k, v, bt, pos, P)
-            kp = jax.lax.dynamic_index_in_dim(pool[:, 0], li, axis=1,
-                                              keepdims=False)
-            vp = jax.lax.dynamic_index_in_dim(pool[:, 1], li, axis=1,
-                                              keepdims=False)
-            o = ops.paged_attention(q, kp, vp, bt, lens,
-                                    impl=scfg.attn_impl)
-            out = o.reshape(b, -1).astype(cd) @ p["attn"]["wo"].astype(cd)
-            out = ctx.tp_comm.psum(out)
-            x = x + out
-            m = lm._decode_mlp(p["mlp"], norm_apply("rms", p["ln2"], x),
-                               ctx, cfg)
+            with jax.named_scope("qkv"):
+                h = norm_apply("rms", p["ln1"], x).astype(cd)
+                q, k, v = attn.project_qkv(p["attn"], h[:, None],
+                                           pos[:, None], cfg, ctx)
+                q, k, v = q[:, 0], k[:, 0], v[:, 0]
+            with jax.named_scope("kv_write"):
+                pool = _write_pages(pool, li, k, v, bt, pos, P)
+            with jax.named_scope("kv_read"):
+                kp = jax.lax.dynamic_index_in_dim(pool[:, 0], li, axis=1,
+                                                  keepdims=False)
+                vp = jax.lax.dynamic_index_in_dim(pool[:, 1], li, axis=1,
+                                                  keepdims=False)
+            with jax.named_scope("attn_kernel"):
+                o = ops.paged_attention(q, kp, vp, bt, lens,
+                                        impl=scfg.attn_impl)
+            with jax.named_scope("attn_out"):
+                out = o.reshape(b, -1).astype(cd) \
+                    @ p["attn"]["wo"].astype(cd)
+                out = ctx.tp_comm.psum(out)
+                x = x + out
+            with jax.named_scope("mlp"):
+                m = lm._decode_mlp(p["mlp"],
+                                   norm_apply("rms", p["ln2"], x), ctx, cfg)
             return (x + m, pool), None
 
         (x, pool), _ = jax.lax.scan(
             body, (x, pool),
             (params["blocks"], jnp.arange(cfg.n_layers)))
-        x = norm_apply("rms" if cfg.family != "encdec" else "layer",
-                       params["ln_f"], x)
-        head = params["embed"] if cfg.tie_embeddings else params["head"]
-        logits = emb.lm_head_logits(head, x.astype(cd), ctx)
-        nxt = sampling.sample_tokens(logits, ctx, samp, pos + 1,
-                                     n_candidates=scfg.sample_candidates)
+        with jax.named_scope("head_sample"):
+            x = norm_apply("rms" if cfg.family != "encdec" else "layer",
+                           params["ln_f"], x)
+            head = params["embed"] if cfg.tie_embeddings else params["head"]
+            logits = emb.lm_head_logits(head, x.astype(cd), ctx)
+            nxt = sampling.sample_tokens(
+                logits, ctx, samp, pos + 1,
+                n_candidates=scfg.sample_candidates)
         return nxt.astype(jnp.int32), pool
 
     return step
@@ -206,7 +218,8 @@ def _make_window_forward(cfg, ctx: ParallelCtx, scfg: ServeConfig):
 
     def window(params, pool, ids, start, n_tok, bt):
         cd = ctx.compute_dtype
-        x = emb.embed_lookup(params["embed"], ids, ctx)
+        with jax.named_scope("embed"):
+            x = emb.embed_lookup(params["embed"], ids, ctx)
         b, t = ids.shape
         pos = start[:, None] + jnp.arange(t)[None]           # (b, t)
         valid = jnp.arange(t)[None] < n_tok[:, None]
@@ -214,38 +227,46 @@ def _make_window_forward(cfg, ctx: ParallelCtx, scfg: ServeConfig):
         def body(carry, inputs):
             x, pool = carry
             p, li = inputs
-            h = norm_apply("rms", p["ln1"], x).astype(cd)
-            q, k, v = attn.project_qkv(p["attn"], h, pos, cfg, ctx)
-            # page writes: token (b, j) -> page bt[b, pos//P] slot
-            # pos%P; the invalid window tail lands in the null page
-            sidx = jnp.clip(pos // P, 0, bt.shape[1] - 1)
-            page = jnp.take_along_axis(bt, sidx, axis=1)     # (b, t)
-            page = jnp.where(valid, page, NULL_PAGE)
-            slot = pos % P
-            dt = pool.dtype
-            pool = pool.at[page, 0, li, slot].set(k.astype(dt))
-            pool = pool.at[page, 1, li, slot].set(v.astype(dt))
-            kp = jax.lax.dynamic_index_in_dim(pool[:, 0], li, axis=1,
-                                              keepdims=False)
-            vp = jax.lax.dynamic_index_in_dim(pool[:, 1], li, axis=1,
-                                              keepdims=False)
-            # whole-window paged attention in one fused call: position
-            # j attends to its first start+j+1 paged tokens (the
-            # chunk's K/V were just written above)
-            o = ops.paged_prefill_attention(q, kp, vp, bt, start, n_tok,
-                                            impl=scfg.attn_impl)
-            out = o.reshape(b, t, -1).astype(cd) @ p["attn"]["wo"].astype(cd)
-            out = ctx.tp_comm.psum(out)
-            x = x + out
-            ctx1 = ctx.with_(sp=False)
-            mlp = (ff.moe_apply if cfg.moe else ff.mlp_apply)(
-                p["mlp"], norm_apply("rms", p["ln2"], x), ctx1, cfg)
+            with jax.named_scope("qkv"):
+                h = norm_apply("rms", p["ln1"], x).astype(cd)
+                q, k, v = attn.project_qkv(p["attn"], h, pos, cfg, ctx)
+            with jax.named_scope("kv_write"):
+                # page writes: token (b, j) -> page bt[b, pos//P] slot
+                # pos%P; the invalid window tail lands in the null page
+                sidx = jnp.clip(pos // P, 0, bt.shape[1] - 1)
+                page = jnp.take_along_axis(bt, sidx, axis=1)  # (b, t)
+                page = jnp.where(valid, page, NULL_PAGE)
+                slot = pos % P
+                dt = pool.dtype
+                pool = pool.at[page, 0, li, slot].set(k.astype(dt))
+                pool = pool.at[page, 1, li, slot].set(v.astype(dt))
+            with jax.named_scope("kv_read"):
+                kp = jax.lax.dynamic_index_in_dim(pool[:, 0], li, axis=1,
+                                                  keepdims=False)
+                vp = jax.lax.dynamic_index_in_dim(pool[:, 1], li, axis=1,
+                                                  keepdims=False)
+            with jax.named_scope("attn_kernel"):
+                # whole-window paged attention in one fused call:
+                # position j attends to its first start+j+1 paged tokens
+                # (the chunk's K/V were just written above)
+                o = ops.paged_prefill_attention(q, kp, vp, bt, start,
+                                                n_tok, impl=scfg.attn_impl)
+            with jax.named_scope("attn_out"):
+                out = o.reshape(b, t, -1).astype(cd) \
+                    @ p["attn"]["wo"].astype(cd)
+                out = ctx.tp_comm.psum(out)
+                x = x + out
+            with jax.named_scope("mlp"):
+                ctx1 = ctx.with_(sp=False)
+                mlp = (ff.moe_apply if cfg.moe else ff.mlp_apply)(
+                    p["mlp"], norm_apply("rms", p["ln2"], x), ctx1, cfg)
             return (x + mlp, pool), None
 
         (x, pool), _ = jax.lax.scan(
             body, (x, pool),
             (params["blocks"], jnp.arange(cfg.n_layers)))
-        return norm_apply("rms", params["ln_f"], x), pool
+        with jax.named_scope("head_sample"):
+            return norm_apply("rms", params["ln_f"], x), pool
 
     return window
 
@@ -269,13 +290,15 @@ def make_prefill(cfg, ctx: ParallelCtx, scfg: ServeConfig):
     def prefill(params, pool, ids, start, n_tok, bt, samp):
         cd = ctx.compute_dtype
         x, pool = window(params, pool, ids, start, n_tok, bt)
-        t = ids.shape[1]
-        last = jnp.clip(n_tok - 1, 0, t - 1)
-        xl = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
-        head = params["embed"] if cfg.tie_embeddings else params["head"]
-        logits = emb.lm_head_logits(head, xl.astype(cd), ctx)
-        nxt = sampling.sample_tokens(logits, ctx, samp, start + n_tok,
-                                     n_candidates=scfg.sample_candidates)
+        with jax.named_scope("head_sample"):
+            t = ids.shape[1]
+            last = jnp.clip(n_tok - 1, 0, t - 1)
+            xl = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
+            head = params["embed"] if cfg.tie_embeddings else params["head"]
+            logits = emb.lm_head_logits(head, xl.astype(cd), ctx)
+            nxt = sampling.sample_tokens(
+                logits, ctx, samp, start + n_tok,
+                n_candidates=scfg.sample_candidates)
         return nxt.astype(jnp.int32), pool
 
     return prefill
@@ -306,13 +329,14 @@ def make_verify(cfg, ctx: ParallelCtx, scfg: ServeConfig):
     def verify(params, pool, ids, start, n_tok, bt, samp):
         cd = ctx.compute_dtype
         x, pool = window(params, pool, ids, start, n_tok, bt)
-        b, t = ids.shape
-        head = params["embed"] if cfg.tie_embeddings else params["head"]
-        logits = emb.lm_head_logits(head, x.astype(cd), ctx)  # (b,t,V/tp)
-        pos = start[:, None] + jnp.arange(t)[None] + 1        # counters
-        nxt = sampling.sample_window_tokens(
-            logits, ctx, samp, pos,
-            n_candidates=scfg.sample_candidates)
+        with jax.named_scope("head_sample"):
+            b, t = ids.shape
+            head = params["embed"] if cfg.tie_embeddings else params["head"]
+            logits = emb.lm_head_logits(head, x.astype(cd), ctx)  # (b,t,V/tp)
+            pos = start[:, None] + jnp.arange(t)[None] + 1        # counters
+            nxt = sampling.sample_window_tokens(
+                logits, ctx, samp, pos,
+                n_candidates=scfg.sample_candidates)
         return nxt.astype(jnp.int32), pool
 
     return verify
@@ -497,27 +521,39 @@ class ServeEngine:
         """One engine tick: hot-swap stream step (when one is in
         flight) -> schedule -> migrate (one quiet) -> chunked prefill
         for every prefilling sequence's quota -> one decode token for
-        every decoding sequence -> retire finished."""
+        every decoding sequence -> retire finished.  Each phase is a
+        host span (``serve.trace``)."""
         self.ticks += 1
-        if self._swap is not None:
-            self._swap_step()
-        plan = self.sched.tick(now)
-        for r in plan.shed:              # deadline drops: never served
-            self.shed.append(r)
-            self._last_tok.pop(r.rid, None)
-            if self.proposer is not None:
-                self.proposer.drop(r.rid)
-        for r in plan.preempted:         # progress resets, gaps with it
-            self._last_tok.pop(r.rid, None)
-            if self.proposer is not None:
-                self.proposer.drop(r.rid)
-        if plan.migrations:
-            self.pool = self.exec.migrate(self.pool,
-                                          tuple(plan.migrations))
-        skip_rids = set()
-        if plan.prefill:
-            skip_rids = self._chunk_prefill(plan.prefill, now)
-        self._decode_tick(skip_rids=skip_rids, now=now)
+        with span("serve.tick", tick=self.ticks):
+            if self._swap is not None:
+                self._swap_step()
+            with span("serve.schedule"):
+                plan = self.sched.tick(now)
+            # the decode batch is fixed once the plan is: sequences that
+            # complete prefill this tick decode from the next one
+            decoding = [r for r in self.sched.running
+                        if not r.is_prefilling()]
+            with span("serve.plan", waiting=len(self.sched.waiting),
+                      decode_seqs=len(decoding),
+                      prefill_seqs=len(plan.prefill),
+                      prefill_tokens=sum(n for _, n in plan.prefill),
+                      pages_free=self.kv.n_free()):
+                for r in plan.shed:      # deadline drops: never served
+                    self.shed.append(r)
+                    self._last_tok.pop(r.rid, None)
+                    if self.proposer is not None:
+                        self.proposer.drop(r.rid)
+                for r in plan.preempted:  # progress resets, gaps with it
+                    self._last_tok.pop(r.rid, None)
+                    if self.proposer is not None:
+                        self.proposer.drop(r.rid)
+            if plan.migrations:
+                with span("serve.migrate", pages=len(plan.migrations)):
+                    self.pool = self.exec.migrate(self.pool,
+                                                  tuple(plan.migrations))
+            if plan.prefill:
+                self._chunk_prefill(plan.prefill, now)
+            self._decode_tick(decoding, now)
 
     def _samp_state(self, reqs) -> dict:
         return sampling.batch_state(reqs, self.scfg.max_batch,
@@ -525,40 +561,43 @@ class ServeEngine:
 
     def _chunk_prefill(self, assignments, now):
         """Feed every (req, n) chunk assignment through the prefill
-        step.  Returns the rids that COMPLETED prefill this tick (their
-        first output token came from the chunk — they must not also
-        decode)."""
+        step.  A sequence whose chunk completes its prompt takes its
+        first output token from the chunk."""
         B, C = self.scfg.max_batch, self.scfg.prefill_chunk
-        reqs = [r for r, _ in assignments]
-        ids = np.zeros((B, C), np.int32)
-        start = np.zeros((B,), np.int32)
-        n_tok = np.zeros((B,), np.int32)
-        for i, (r, n) in enumerate(assignments):
-            ids[i, :n] = r.prompt[r.n_done:r.n_done + n]
-            start[i] = r.n_done
-            n_tok[i] = n
-        bt = self.kv.block_table(
-            [r.rid for r in reqs] + [None] * (B - len(reqs)),
-            self.scfg.table_slots)
-        toks, self.pool = self.exec.prefill(self.pool, ids, start, n_tok,
-                                            bt, self._samp_state(reqs))
-        toks = np.asarray(toks)
-        done = set()
-        for i, (r, n) in enumerate(assignments):
-            self.sched.note_chunk(r, n, int(toks[i]), now)
-            if not r.is_prefilling():
-                done.add(r.rid)
-                if self.role == "prefill" and not r.finished():
-                    # prefill cell: this sequence's life here ends with
-                    # its first token — park it for the page handoff
-                    # (pages stay resident as the put-signal payload
-                    # source until the decode cell acknowledges)
-                    self.sched.release(r)
-                    self.handoff_ready.append(r)
-                    continue
-                self._last_tok[r.rid] = now
-                self._maybe_finish(r, now)
-        return done
+        with span("serve.prepare", step="prefill"):
+            reqs = [r for r, _ in assignments]
+            ids = np.zeros((B, C), np.int32)
+            start = np.zeros((B,), np.int32)
+            n_tok = np.zeros((B,), np.int32)
+            for i, (r, n) in enumerate(assignments):
+                ids[i, :n] = r.prompt[r.n_done:r.n_done + n]
+                start[i] = r.n_done
+                n_tok[i] = n
+            bt = self.kv.block_table(
+                [r.rid for r in reqs] + [None] * (B - len(reqs)),
+                self.scfg.table_slots)
+            samp = self._samp_state(reqs)
+        with span("serve.dispatch", step="prefill",
+                  tokens=sum(n for _, n in assignments)):
+            toks, self.pool = self.exec.prefill(self.pool, ids, start,
+                                                n_tok, bt, samp)
+        with span("serve.wait", step="prefill"):
+            toks = np.asarray(toks)
+        with span("serve.retire", step="prefill"):
+            for i, (r, n) in enumerate(assignments):
+                self.sched.note_chunk(r, n, int(toks[i]), now)
+                if not r.is_prefilling():
+                    if self.role == "prefill" and not r.finished():
+                        # prefill cell: this sequence's life here ends
+                        # with its first token — park it for the page
+                        # handoff (pages stay resident as the put-signal
+                        # payload source until the decode cell
+                        # acknowledges)
+                        self.sched.release(r)
+                        self.handoff_ready.append(r)
+                        continue
+                    self._last_tok[r.rid] = now
+                    self._maybe_finish(r, now)
 
     def adopt_request(self, req: Request, pages, now: float = 0.0) -> None:
         """Decode-cell half of a disaggregated handoff: attach the
@@ -573,37 +612,41 @@ class ServeEngine:
         # inter-token gap is measured from adoption
         self._last_tok[req.rid] = now
 
-    def _decode_tick(self, skip_rids, now):
-        if self.role == "prefill":
-            return
-        batch = [r for r in self.sched.running
-                 if not r.is_prefilling() and r.rid not in skip_rids]
-        if not batch:
+    def _decode_tick(self, batch, now):
+        """One decode token for every sequence of ``batch`` (the running
+        sequences that had finished prefill when the tick was
+        planned)."""
+        if self.role == "prefill" or not batch:
             return
         if self.scfg.spec_k > 0:
             return self._spec_tick(batch, now)
         B = self.scfg.max_batch
-        tokens = np.zeros((B,), np.int32)
-        pos = np.zeros((B,), np.int32)
-        lens = np.zeros((B,), np.int32)
-        for i, r in enumerate(batch):
-            tokens[i] = r.next_input()
-            p = r.n_prompt + len(r.out) - 1
-            pos[i] = p
-            lens[i] = p + 1
-        bt = self.kv.block_table(
-            [r.rid for r in batch] + [None] * (B - len(batch)),
-            self.scfg.table_slots)
-        toks, self.pool = self.exec.decode(self.pool, tokens, pos, bt,
-                                           lens, self._samp_state(batch))
-        toks = np.asarray(toks)
-        for i, r in enumerate(batch):
-            self.sched.advance(r, int(toks[i]), now)
-            prev = self._last_tok.get(r.rid)
-            if prev is not None:
-                self.itl.append(now - prev)
-            self._last_tok[r.rid] = now
-            self._maybe_finish(r, now)
+        with span("serve.prepare", step="decode"):
+            tokens = np.zeros((B,), np.int32)
+            pos = np.zeros((B,), np.int32)
+            lens = np.zeros((B,), np.int32)
+            for i, r in enumerate(batch):
+                tokens[i] = r.next_input()
+                p = r.n_prompt + len(r.out) - 1
+                pos[i] = p
+                lens[i] = p + 1
+            bt = self.kv.block_table(
+                [r.rid for r in batch] + [None] * (B - len(batch)),
+                self.scfg.table_slots)
+            samp = self._samp_state(batch)
+        with span("serve.dispatch", step="decode", tokens=len(batch)):
+            toks, self.pool = self.exec.decode(self.pool, tokens, pos, bt,
+                                               lens, samp)
+        with span("serve.wait", step="decode"):
+            toks = np.asarray(toks)
+        with span("serve.retire", step="decode"):
+            for i, r in enumerate(batch):
+                self.sched.advance(r, int(toks[i]), now)
+                prev = self._last_tok.get(r.rid)
+                if prev is not None:
+                    self.itl.append(now - prev)
+                self._last_tok[r.rid] = now
+                self._maybe_finish(r, now)
 
     def _spec_tick(self, batch, now):
         """Draft -> verify -> accept -> rewind, one batched verify
@@ -622,59 +665,64 @@ class ServeEngine:
         Rejected positions rewind: page-granular ``kv.truncate`` plus
         the length bookkeeping the scheduler already keeps."""
         B, K = self.scfg.max_batch, self.scfg.spec_k
-        allow = [self.sched.draft_allowance(r) for r in batch]
-        drafts = self.proposer.propose(batch, allow)
-        ids = np.zeros((B, K + 1), np.int32)
-        start = np.zeros((B,), np.int32)
-        n_tok = np.zeros((B,), np.int32)
-        for i, r in enumerate(batch):
-            d = drafts[i][:allow[i]]
-            drafts[i] = d
-            ids[i, 0] = r.next_input()
-            if d:
-                ids[i, 1:1 + len(d)] = d
-            start[i] = r.n_prompt + len(r.out) - 1
-            n_tok[i] = 1 + len(d)
-        bt = self.kv.block_table(
-            [r.rid for r in batch] + [None] * (B - len(batch)),
-            self.scfg.table_slots)
-        toks, self.pool = self.exec.verify(self.pool, ids, start, n_tok,
-                                           bt, self._samp_state(batch))
-        toks = np.asarray(toks)
-        self.spec_stats["verify_ticks"] += 1
-        self.spec_stats["verify_seqs"] += len(batch)
-        for i, r in enumerate(batch):
-            d = drafts[i]
-            m = 0
-            while m < len(d) and int(toks[i, m]) == int(d[m]):
-                m += 1
-            # the allowance already caps drafts at the output budget,
-            # so emitting every accepted token can never overshoot
-            emit = min(m + 1, r.max_new - len(r.out))
-            self.spec_stats["drafted"] += len(d)
-            self.spec_stats["accepted"] += m
-            self.spec_stats["emitted"] += emit
-            prev = self._last_tok.get(r.rid)
-            for j in range(emit):
-                self.sched.advance(r, int(toks[i, j]), now)
-                if prev is not None:
-                    # tokens of one verify pass arrive together: the
-                    # first closes the inter-token gap, the rest are
-                    # free (that IS the latency win)
-                    self.itl.append(now - prev if j == 0 else 0.0)
-            self._last_tok[r.rid] = now
-            if r.finished():
-                self._maybe_finish(r, now)
-                continue
-            if not d:
-                continue      # nothing speculative was written: the
-                              # allowance pages stay attached for the
-                              # next window (no alloc/free churn)
-            # rewind: K/V is valid through the last ACCEPTED position
-            # (the newest sampled token's K/V is written when it is fed
-            # next tick, same as non-speculative decode)
-            self.kv.truncate(r.rid, r.n_prompt + len(r.out) - 1)
-            self.proposer.rewind(r.rid, r.n_prompt + len(r.out) - 1)
+        with span("serve.prepare", step="verify"):
+            allow = [self.sched.draft_allowance(r) for r in batch]
+            drafts = self.proposer.propose(batch, allow)
+            ids = np.zeros((B, K + 1), np.int32)
+            start = np.zeros((B,), np.int32)
+            n_tok = np.zeros((B,), np.int32)
+            for i, r in enumerate(batch):
+                d = drafts[i][:allow[i]]
+                drafts[i] = d
+                ids[i, 0] = r.next_input()
+                if d:
+                    ids[i, 1:1 + len(d)] = d
+                start[i] = r.n_prompt + len(r.out) - 1
+                n_tok[i] = 1 + len(d)
+            bt = self.kv.block_table(
+                [r.rid for r in batch] + [None] * (B - len(batch)),
+                self.scfg.table_slots)
+            samp = self._samp_state(batch)
+        with span("serve.dispatch", step="verify", tokens=int(n_tok.sum())):
+            toks, self.pool = self.exec.verify(self.pool, ids, start,
+                                               n_tok, bt, samp)
+        with span("serve.wait", step="verify"):
+            toks = np.asarray(toks)
+        with span("serve.retire", step="verify"):
+            self.spec_stats["verify_ticks"] += 1
+            self.spec_stats["verify_seqs"] += len(batch)
+            for i, r in enumerate(batch):
+                d = drafts[i]
+                m = 0
+                while m < len(d) and int(toks[i, m]) == int(d[m]):
+                    m += 1
+                # the allowance already caps drafts at the output budget,
+                # so emitting every accepted token can never overshoot
+                emit = min(m + 1, r.max_new - len(r.out))
+                self.spec_stats["drafted"] += len(d)
+                self.spec_stats["accepted"] += m
+                self.spec_stats["emitted"] += emit
+                prev = self._last_tok.get(r.rid)
+                for j in range(emit):
+                    self.sched.advance(r, int(toks[i, j]), now)
+                    if prev is not None:
+                        # tokens of one verify pass arrive together: the
+                        # first closes the inter-token gap, the rest are
+                        # free (that IS the latency win)
+                        self.itl.append(now - prev if j == 0 else 0.0)
+                self._last_tok[r.rid] = now
+                if r.finished():
+                    self._maybe_finish(r, now)
+                    continue
+                if not d:
+                    continue      # nothing speculative was written: the
+                                  # allowance pages stay attached for the
+                                  # next window (no alloc/free churn)
+                # rewind: K/V is valid through the last ACCEPTED position
+                # (the newest sampled token's K/V is written when it is fed
+                # next tick, same as non-speculative decode)
+                self.kv.truncate(r.rid, r.n_prompt + len(r.out) - 1)
+                self.proposer.rewind(r.rid, r.n_prompt + len(r.out) - 1)
 
     def _maybe_finish(self, r, now):
         if not r.is_prefilling() and r.finished():

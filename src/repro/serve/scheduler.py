@@ -70,6 +70,7 @@ class Request:
     n_done: int = 0          # prompt tokens whose KV is in pages
     prefill_chunks: list = dataclasses.field(default_factory=list)
     slot: Optional[int] = None
+    t_admit: Optional[float] = None   # first admission; kept on preemption
     t_first: Optional[float] = None
     t_finish: Optional[float] = None
     preemptions: int = 0
@@ -207,7 +208,7 @@ class FCFSScheduler:
         for r in plan.preempted:
             budget += quotas.pop(r.rid, 0)
         budget += self._decode_refund
-        self._admit(plan, quotas, budget)
+        self._admit(plan, quotas, budget, now)
         plan.prefill = [(r, quotas[r.rid]) for r in self.running
                         if r.rid in quotas]
         self.stats["prefill_tokens"] += sum(n for _, n in plan.prefill)
@@ -313,7 +314,8 @@ class FCFSScheduler:
                           r, self._arrive_idx.setdefault(
                               r.rid, next(self._arrive_seq))))
 
-    def _admit(self, plan: TickPlan, quotas: dict, budget: int) -> None:
+    def _admit(self, plan: TickPlan, quotas: dict, budget: int,
+               now: float) -> None:
         preempted_rids = {r.rid for r in plan.preempted}
         for req in self._admission_order():
             if len(self.running) >= self.max_batch:
@@ -333,7 +335,7 @@ class FCFSScheduler:
                 # same-PE owner: the identical put_nbi path with
                 # self-pairs — a 0-hop page copy into fresh pages, so
                 # the pinned originals stay in the index
-                if not self._admit_resumed(req, hit, plan):
+                if not self._admit_resumed(req, hit, plan, now):
                     if self.slo is not None:
                         self.slo.admit_refund(req)
                     break
@@ -344,12 +346,13 @@ class FCFSScheduler:
                         self.slo.admit_refund(req)
                     break
                 self.waiting.remove(req)     # identity (eq=False)
-                self._start(req)
+                self._start(req, now)
                 plan.admitted.append(req)
                 self.stats["admitted"] += 1
             budget = self._grant(req, quotas, budget, guarantee=True)
 
-    def _admit_resumed(self, req: Request, hit, plan: TickPlan) -> bool:
+    def _admit_resumed(self, req: Request, hit, plan: TickPlan,
+                       now: float) -> bool:
         """Prefix pages live on another PE: take landing pages, plan the
         migrations, and admit with the prefix marked done — the rest of
         the prompt streams through the chunked-prefill path."""
@@ -365,7 +368,7 @@ class FCFSScheduler:
             PageMigration(owner_pe, self.my_pe, s, d)
             for s, d in zip(src_pages, landing))
         self.waiting.remove(req)             # identity (eq=False)
-        self._start(req)
+        self._start(req, now)
         # leave >= 1 prompt token to feed: re-feeding the boundary token
         # rewrites identical KV (idempotent) and yields the next logits
         covered = len(landing) * self.kv.page_tokens
@@ -375,9 +378,14 @@ class FCFSScheduler:
         self.kv.stats["prefix_hits"] += 1
         return True
 
-    def _start(self, req: Request) -> None:
+    def _start(self, req: Request, now: Optional[float] = None) -> None:
+        """Enter ``req`` into the running set.  ``now`` is the admitting
+        tick's time, stamped on the first admission only (a handed-off
+        sequence, which passes none, was admitted by its producer)."""
         self.running.append(req)
         self._admit_idx[req.rid] = next(self._admit_seq)
+        if req.t_admit is None:
+            req.t_admit = now
 
     # ------------------------------------------------------------------
     # disaggregated handoff (serve.disagg): a sequence leaves one cell's
