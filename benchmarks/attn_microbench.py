@@ -91,17 +91,18 @@ def _inputs(kind, window, page_tokens, slots, dtype, seed=0):
     n_pages = B * slots + 1
     kp = jnp.asarray(rng.randn(n_pages, page_tokens, HKV, D)).astype(dtype)
     vp = jnp.asarray(rng.randn(n_pages, page_tokens, HKV, D)).astype(dtype)
+    pool = jnp.stack([kp, vp], axis=1)[:, :, None]   # one layer
     bt = jnp.asarray(rng.permutation(np.arange(1, n_pages))
                      .reshape(B, slots).astype(np.int32))
     span = page_tokens * slots
     if kind == "decode":
         q = jnp.asarray(rng.randn(B, H, D)).astype(dtype)
         lens = jnp.asarray(rng.randint(1, span + 1, B), jnp.int32)
-        return q, kp, vp, bt, lens
+        return q, pool, 0, bt, lens
     q = jnp.asarray(rng.randn(B, window, H, D)).astype(dtype)
     start = jnp.asarray(rng.randint(0, span - window + 1, B), jnp.int32)
     n_tok = jnp.asarray(rng.randint(1, window + 1, B), jnp.int32)
-    return q, kp, vp, bt, start, n_tok
+    return q, pool, 0, bt, start, n_tok
 
 
 def run_pair(kind, window, page_tokens, slots, dt, *, block_q=None):

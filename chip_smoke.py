@@ -139,11 +139,12 @@ def check_attention_parity(cfg, scfg, max_len: int) -> None:
                   scfg.page_tokens)
     H, Hkv, D = cfg.n_heads, cfg.kv_per_rank(1), cfg.head_dim
     dt = scfg.kv_dtype
-    kk, kv_, kq, kw = jax.random.split(jax.random.PRNGKey(SEED), 4)
-    k = jax.random.normal(kk, (scfg.n_pages, P, Hkv, D), dt)
-    v = jax.random.normal(kv_, (scfg.n_pages, P, Hkv, D), dt)
+    kp, kq, kw = jax.random.split(jax.random.PRNGKey(SEED), 3)
+    # a two-layer pool, read at its second layer
+    pool = jax.random.normal(kp, (scfg.n_pages, 2, 2, P, Hkv, D), dt)
+    layer = 1
     bt = jnp.asarray(rng.randint(1, scfg.n_pages, (B, S)), jnp.int32)
-    vmax = float(jnp.abs(v).max())
+    vmax = float(jnp.abs(pool[:, 1, layer]).max())
 
     def close(name, got, want):
         got = np.asarray(got, np.float32)
@@ -162,8 +163,8 @@ def check_attention_parity(cfg, scfg, max_len: int) -> None:
     q = jax.random.normal(kq, (B, H, D), dt)
     lens = jnp.asarray(lens, jnp.int32)
     close("paged decode attention",
-          ops.paged_attention(q, k, v, bt, lens, impl="kernel"),
-          ops.paged_attention(q, k, v, bt, lens, impl="ref"))
+          ops.paged_attention(q, pool, layer, bt, lens, impl="kernel"),
+          ops.paged_attention(q, pool, layer, bt, lens, impl="ref"))
 
     start = rng.randint(0, max_len - C + 1, B)
     n_tok = rng.randint(1, C + 1, B)
@@ -172,9 +173,9 @@ def check_attention_parity(cfg, scfg, max_len: int) -> None:
     qw = jax.random.normal(kw, (B, C, H, D), dt)
     start, n_tok = (jnp.asarray(a, jnp.int32) for a in (start, n_tok))
     close("paged prefill attention",
-          ops.paged_prefill_attention(qw, k, v, bt, start, n_tok,
+          ops.paged_prefill_attention(qw, pool, layer, bt, start, n_tok,
                                       impl="kernel"),
-          ops.paged_prefill_attention(qw, k, v, bt, start, n_tok,
+          ops.paged_prefill_attention(qw, pool, layer, bt, start, n_tok,
                                       impl="ref"))
 
 

@@ -47,35 +47,38 @@ def attention(q, k, v, causal: bool = True, window: int | None = None,
 
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "impl"))
-def paged_attention(q, k_pages, v_pages, block_tables, lengths,
+def paged_attention(q, kv_pool, layer, block_tables, lengths,
                     sm_scale: float | None = None, impl: str = "kernel"):
     """Paged decode attention (serving hot path): K/V gathered through a
-    block table of symmetric-heap pages.  ``impl="kernel"`` runs the
-    Pallas kernel (compiled on TPU, interpret elsewhere); ``"ref"`` the
-    jnp oracle — numerically interchangeable (tier-1 parity test)."""
+    block table of symmetric-heap pages, straight from the whole pool
+    ``(n_pages, 2, L, P, H_kv, D)`` at ``layer``.  ``impl="kernel"``
+    runs the Pallas kernel (compiled on TPU, interpret elsewhere);
+    ``"ref"`` the jnp oracle — numerically interchangeable (tier-1
+    parity test)."""
     if impl == "ref":
-        return _pa.paged_decode_attention_ref(q, k_pages, v_pages,
+        return _pa.paged_decode_attention_ref(q, kv_pool, layer,
                                               block_tables, lengths,
                                               sm_scale=sm_scale)
     if impl != "kernel":
         raise ValueError(
             f"paged_attention impl='{impl}' "
             f"(choose from {PAGED_ATTN_IMPLS})")
-    return _pa.paged_decode_attention(q, k_pages, v_pages, block_tables,
+    return _pa.paged_decode_attention(q, kv_pool, layer, block_tables,
                                       lengths, sm_scale=sm_scale,
                                       interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "impl", "block_q"))
-def paged_prefill_attention(q, k_pages, v_pages, block_tables, start,
+def paged_prefill_attention(q, kv_pool, layer, block_tables, start,
                             n_tok, sm_scale: float | None = None,
                             impl: str = "ref", block_q: int | None = None):
     """Chunk-window attention through a block table: query row ``j`` of
     sequence ``b`` (absolute position ``start[b] + j``) attends to its
-    first ``start[b]+j+1`` paged tokens; padded rows (``j >= n_tok``)
-    return zeros.  This is BOTH the chunked-prefill window and the
-    speculative-decode verify window (a ``(B, k+1)`` window of pending
-    token + drafts — ``serve.make_verify``): numerically the same
+    first ``start[b]+j+1`` paged tokens of ``layer`` in the whole pool;
+    padded rows (``j >= n_tok``) return zeros.  This is BOTH the
+    chunked-prefill window and the speculative-decode verify window (a
+    ``(B, k+1)`` window of pending token + drafts —
+    ``serve.make_verify``): numerically the same
     per-position reduction as ``paged_attention(impl="ref")``, which is
     what lets verify-path token streams match sequential decoding.
 
@@ -87,14 +90,14 @@ def paged_prefill_attention(q, k_pages, v_pages, block_tables, start,
     ``choose_block`` size/dtype dispatch; windows are padded to a block
     multiple and sliced back."""
     if impl == "ref":
-        return _pa.paged_prefill_attention_ref(q, k_pages, v_pages,
+        return _pa.paged_prefill_attention_ref(q, kv_pool, layer,
                                                block_tables, start, n_tok,
                                                sm_scale=sm_scale)
     if impl != "kernel":
         raise ValueError(
             f"paged_prefill_attention impl='{impl}' "
             f"(choose from {PAGED_PREFILL_IMPLS})")
-    return _pa.paged_prefill_attention(q, k_pages, v_pages, block_tables,
+    return _pa.paged_prefill_attention(q, kv_pool, layer, block_tables,
                                        start, n_tok, sm_scale=sm_scale,
                                        block_q=block_q,
                                        interpret=_interpret())

@@ -26,6 +26,11 @@ f32 accumulator live in VMEM scratch across table slots
 paged sequence produces the same reduction tree as a contiguous one
 with ``block_kv == page_tokens``.
 
+Both take the engine's whole pool, ``(n_pages, 2, L, P, H_kv, D)``,
+and a layer index: the index map picks ``(page, k|v, layer)``, a
+contiguous ``(P, H_kv, D)`` page, so no caller slices a layer (or a K
+or V half) out of the pool before the call.
+
 GQA is handled by a static loop over KV heads (query rows grouped by
 the KV head they read), matching the cache layout: pages store
 ``kv_per_rank`` heads, queries ``heads_per_rank``.
@@ -120,10 +125,26 @@ def _normalized(acc_ref, l_ref, rows):
     return acc_ref[rows, :] / denom
 
 
+def _page_spec(half, n_grid, n_slots, page_tokens, hkv, d):
+    """The K (``half=0``) or V (``half=1``) block of one table slot: the
+    ``(P, H_kv, D)`` page at ``(block_table[seq, slot], half, layer)``
+    of the whole pool.  Both grids lead with the sequence and end with
+    the table slot; the scalar-prefetch operands lead with the
+    flattened block table and end with the layer."""
+    def index(*args):
+        ids, bt, layer = args[:n_grid], args[n_grid], args[-1]
+        return (bt[ids[0] * n_slots + ids[-1]], half, layer[0], 0, 0, 0)
+    return pl.BlockSpec((None, None, None, page_tokens, hkv, d), index)
+
+
+def _layer_operand(layer) -> jax.Array:
+    return jnp.asarray(layer, jnp.int32).reshape(1)
+
+
 # ======================================================================
 # decode kernel: one query per sequence, grid (sequence, table slot)
 # ======================================================================
-def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
+def _paged_kernel(bt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
                   acc_ref, m_ref, l_ref, *, sm_scale: float,
                   page_tokens: int, n_slots: int, hkv: int, group: int):
     i = pl.program_id(0)          # sequence
@@ -144,8 +165,8 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         valid = cols < length
         for h in range(hkv):                          # static GQA loop
             qh = q[h * group:(h + 1) * group]         # (g, D)
-            kh = k_ref[0, :, h, :].astype(jnp.float32)   # (P, D)
-            vh = v_ref[0, :, h, :].astype(jnp.float32)
+            kh = k_ref[:, h, :].astype(jnp.float32)   # (P, D)
+            vh = v_ref[:, h, :].astype(jnp.float32)
             s = jax.lax.dot_general(qh, kh, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
             s = jnp.where(valid, s * sm_scale, NEG_INF)   # (g, P)
@@ -159,15 +180,17 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                                slice(None)).astype(o_ref.dtype)
 
 
-def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
-                           v_pages: jax.Array, block_tables: jax.Array,
+def paged_decode_attention(q: jax.Array, kv_pool: jax.Array,
+                           layer: jax.Array, block_tables: jax.Array,
                            lengths: jax.Array, *,
                            sm_scale: float | None = None,
                            interpret: bool | None = None) -> jax.Array:
     """One decode step of attention through a block table.
 
     q:            (B, H, D) this step's queries
-    k/v_pages:    (n_pages, P, H_kv, D) the page pool (H % H_kv == 0)
+    kv_pool:      (n_pages, 2, L, P, H_kv, D) the page pool, K at
+                  ``[:, 0]`` and V at ``[:, 1]`` (H % H_kv == 0)
+    layer:        int32 scalar, the layer whose pages are read
     block_tables: (B, n_slots) int32 page ids (unused slots: any valid id)
     lengths:      (B,) int32 tokens valid per sequence (0 = inactive ->
                   zero output)
@@ -178,7 +201,7 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     if interpret is None:
         interpret = _sc.default_interpret()
     b, h, d = q.shape
-    n_pages, page_tokens, hkv, _ = k_pages.shape
+    page_tokens, hkv = kv_pool.shape[3:5]
     if h % hkv:
         raise ValueError(f"GQA requires H % H_kv == 0, got {h} % {hkv}")
     group = h // hkv
@@ -192,19 +215,16 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     bt_flat = block_tables.reshape(-1).astype(jnp.int32)
     lens = lengths.astype(jnp.int32)
 
-    def q_map(i, j, bt, ln):
+    def q_map(i, j, bt, ln, li):
         return (i, 0, 0)
 
-    def kv_map(i, j, bt, ln):
-        return (bt[i * n_slots + j], 0, 0, 0)
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, n_slots),
         in_specs=[
             pl.BlockSpec((1, h, d), q_map),
-            pl.BlockSpec((1, page_tokens, hkv, d), kv_map),
-            pl.BlockSpec((1, page_tokens, hkv, d), kv_map),
+            _page_spec(0, 2, n_slots, page_tokens, hkv, d),
+            _page_spec(1, 2, n_slots, page_tokens, hkv, d),
         ],
         out_specs=pl.BlockSpec((1, h, d), q_map),
         scratch_shapes=[
@@ -218,14 +238,14 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
-    )(bt_flat, lens, q, k_pages, v_pages)
+    )(bt_flat, lens, _layer_operand(layer), q, kv_pool, kv_pool)
 
 
 # ======================================================================
 # prefill-window kernel: grid (sequence, q block, table slot)
 # ======================================================================
-def _prefill_kernel(bt_ref, start_ref, ntok_ref, q_ref, k_ref, v_ref,
-                    o_ref, acc_ref, m_ref, l_ref, *, sm_scale: float,
+def _prefill_kernel(bt_ref, start_ref, ntok_ref, layer_ref, q_ref, k_ref,
+                    v_ref, o_ref, acc_ref, m_ref, l_ref, *, sm_scale: float,
                     page_tokens: int, n_slots: int, block_q: int,
                     hkv: int, group: int, head_dim: int):
     i = pl.program_id(0)          # sequence
@@ -261,8 +281,8 @@ def _prefill_kernel(bt_ref, start_ref, ntok_ref, q_ref, k_ref, v_ref,
         valid = (cols <= start + jrow) & (jrow < ntok)
         for h in range(hkv):                      # static GQA loop
             qh = q[:, h * group:(h + 1) * group, :].reshape(r, head_dim)
-            kh = k_ref[0, :, h, :].astype(jnp.float32)   # (P, D)
-            vh = v_ref[0, :, h, :].astype(jnp.float32)
+            kh = k_ref[:, h, :].astype(jnp.float32)   # (P, D)
+            vh = v_ref[:, h, :].astype(jnp.float32)
             s = jax.lax.dot_general(qh, kh, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
             s = jnp.where(valid, s * sm_scale, NEG_INF)  # (r, P)
@@ -280,8 +300,8 @@ def _prefill_kernel(bt_ref, start_ref, ntok_ref, q_ref, k_ref, v_ref,
                 block_q, group, head_dim).astype(o_ref.dtype)
 
 
-def paged_prefill_attention(q: jax.Array, k_pages: jax.Array,
-                            v_pages: jax.Array, block_tables: jax.Array,
+def paged_prefill_attention(q: jax.Array, kv_pool: jax.Array,
+                            layer: jax.Array, block_tables: jax.Array,
                             start: jax.Array, n_tok: jax.Array, *,
                             sm_scale: float | None = None,
                             block_q: int | None = None,
@@ -293,7 +313,8 @@ def paged_prefill_attention(q: jax.Array, k_pages: jax.Array,
     q:            (B, C, H, D) one prefill CHUNK (or spec-verify
                   window) of queries; row j of sequence b sits at
                   absolute position ``start[b] + j``
-    k/v_pages:    (n_pages, P, H_kv, D) the page pool
+    kv_pool:      (n_pages, 2, L, P, H_kv, D) the page pool
+    layer:        int32 scalar, the layer whose pages are read
     block_tables: (B, n_slots) int32 page ids (null-padded past the
                   live pages)
     start:        (B,) absolute position of q[:, 0]
@@ -313,7 +334,7 @@ def paged_prefill_attention(q: jax.Array, k_pages: jax.Array,
     if interpret is None:
         interpret = _sc.default_interpret()
     b, c, h, d = q.shape
-    n_pages, page_tokens, hkv, _ = k_pages.shape
+    page_tokens, hkv = kv_pool.shape[3:5]
     if h % hkv:
         raise ValueError(f"GQA requires H % H_kv == 0, got {h} % {hkv}")
     group = h // hkv
@@ -334,19 +355,16 @@ def paged_prefill_attention(q: jax.Array, k_pages: jax.Array,
     starts = start.astype(jnp.int32)
     ntoks = n_tok.astype(jnp.int32)
 
-    def q_map(i, qi, jk, bt, st, nt):
+    def q_map(i, qi, jk, bt, st, nt, li):
         return (i, qi, 0, 0)
 
-    def kv_map(i, qi, jk, bt, st, nt):
-        return (bt[i * n_slots + jk], 0, 0, 0)
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(b, n_q, n_slots),
         in_specs=[
             pl.BlockSpec((1, block_q, h, d), q_map),
-            pl.BlockSpec((1, page_tokens, hkv, d), kv_map),
-            pl.BlockSpec((1, page_tokens, hkv, d), kv_map),
+            _page_spec(0, 3, n_slots, page_tokens, hkv, d),
+            _page_spec(1, 3, n_slots, page_tokens, hkv, d),
         ],
         out_specs=pl.BlockSpec((1, block_q, h, d), q_map),
         scratch_shapes=[
@@ -360,14 +378,24 @@ def paged_prefill_attention(q: jax.Array, k_pages: jax.Array,
         out_shape=jax.ShapeDtypeStruct((b, cp, h, d), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
-    )(bt_flat, starts, ntoks, qp, k_pages, v_pages)
+    )(bt_flat, starts, ntoks, _layer_operand(layer), qp, kv_pool, kv_pool)
     return out[:, :c]
 
 
 # ======================================================================
 # jnp oracles
 # ======================================================================
-def paged_prefill_attention_ref(q, k_pages, v_pages, block_tables,
+def _gather_pages(kv_pool, layer, block_tables):
+    """K and V of the named pages at ``layer``, each (B, S, H_kv, D):
+    only the pages the tables list, never a whole pool half."""
+    b = block_tables.shape[0]
+    _, _, _, page_tokens, hkv, d = kv_pool.shape
+    s_max = block_tables.shape[1] * page_tokens
+    return (kv_pool[block_tables, 0, layer].reshape(b, s_max, hkv, d),
+            kv_pool[block_tables, 1, layer].reshape(b, s_max, hkv, d))
+
+
+def paged_prefill_attention_ref(q, kv_pool, layer, block_tables,
                                 start, n_tok, *,
                                 sm_scale: float | None = None):
     """Chunk-window prefill attention through the block table.
@@ -387,14 +415,11 @@ def paged_prefill_attention_ref(q, k_pages, v_pages, block_tables,
     CPU path in the engine.
     """
     b, c, h, d = q.shape
-    _, page_tokens, hkv, _ = k_pages.shape
+    kc, vc = _gather_pages(kv_pool, layer, block_tables)
+    s_max, hkv = kc.shape[1:3]
     group = h // hkv
-    n_slots = block_tables.shape[1]
-    s_max = n_slots * page_tokens
     sm_scale = 1.0 / math.sqrt(d) if sm_scale is None else sm_scale
 
-    kc = k_pages[block_tables].reshape(b, s_max, hkv, d)
-    vc = v_pages[block_tables].reshape(b, s_max, hkv, d)
     qg = q.reshape(b, c, hkv, group, d).astype(jnp.float32)
     sc = jnp.einsum("bchgd,bshd->bchgs", qg, kc.astype(jnp.float32),
                     preferred_element_type=jnp.float32) * sm_scale
@@ -412,21 +437,17 @@ def paged_prefill_attention_ref(q, k_pages, v_pages, block_tables,
     return out.reshape(b, c, h, d).astype(q.dtype)
 
 
-def paged_decode_attention_ref(q, k_pages, v_pages, block_tables, lengths,
+def paged_decode_attention_ref(q, kv_pool, layer, block_tables, lengths,
                                *, sm_scale: float | None = None):
     """jnp oracle: gather the pages, dense masked softmax in f32.
     Mathematically identical to the kernel (same mask, same scale);
     the fast path off-TPU."""
     b, h, d = q.shape
-    _, page_tokens, hkv, _ = k_pages.shape
+    kc, vc = _gather_pages(kv_pool, layer, block_tables)
+    s_max, hkv = kc.shape[1:3]
     group = h // hkv
-    n_slots = block_tables.shape[1]
-    s_max = n_slots * page_tokens
     sm_scale = 1.0 / math.sqrt(d) if sm_scale is None else sm_scale
 
-    # (B, n_slots, P, hkv, d) -> (B, S, hkv, d)
-    kc = k_pages[block_tables].reshape(b, s_max, hkv, d)
-    vc = v_pages[block_tables].reshape(b, s_max, hkv, d)
     qg = q.reshape(b, hkv, group, d).astype(jnp.float32)
     sc = jnp.einsum("bhgd,bshd->bhgs", qg, kc.astype(jnp.float32),
                     preferred_element_type=jnp.float32) * sm_scale

@@ -164,13 +164,8 @@ def make_decode_step(cfg, ctx: ParallelCtx, scfg: ServeConfig):
                 q, k, v = q[:, 0], k[:, 0], v[:, 0]
             with jax.named_scope("kv_write"):
                 pool = _write_pages(pool, li, k, v, bt, pos, P)
-            with jax.named_scope("kv_read"):
-                kp = jax.lax.dynamic_index_in_dim(pool[:, 0], li, axis=1,
-                                                  keepdims=False)
-                vp = jax.lax.dynamic_index_in_dim(pool[:, 1], li, axis=1,
-                                                  keepdims=False)
             with jax.named_scope("attn_kernel"):
-                o = ops.paged_attention(q, kp, vp, bt, lens,
+                o = ops.paged_attention(q, pool, li, bt, lens,
                                         impl=scfg.attn_impl)
             with jax.named_scope("attn_out"):
                 out = o.reshape(b, -1).astype(cd) \
@@ -240,16 +235,11 @@ def _make_window_forward(cfg, ctx: ParallelCtx, scfg: ServeConfig):
                 dt = pool.dtype
                 pool = pool.at[page, 0, li, slot].set(k.astype(dt))
                 pool = pool.at[page, 1, li, slot].set(v.astype(dt))
-            with jax.named_scope("kv_read"):
-                kp = jax.lax.dynamic_index_in_dim(pool[:, 0], li, axis=1,
-                                                  keepdims=False)
-                vp = jax.lax.dynamic_index_in_dim(pool[:, 1], li, axis=1,
-                                                  keepdims=False)
             with jax.named_scope("attn_kernel"):
                 # whole-window paged attention in one fused call:
                 # position j attends to its first start+j+1 paged tokens
-                # (the chunk's K/V were just written above)
-                o = ops.paged_prefill_attention(q, kp, vp, bt, start,
+                # of layer li (the chunk's K/V were just written above)
+                o = ops.paged_prefill_attention(q, pool, li, bt, start,
                                                 n_tok, impl=scfg.attn_impl)
             with jax.named_scope("attn_out"):
                 out = o.reshape(b, t, -1).astype(cd) \

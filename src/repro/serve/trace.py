@@ -23,8 +23,9 @@ Keyword counters become the span's stats in the trace.
 ``step`` is ``prefill``, ``decode`` or ``verify``; prepare, dispatch,
 wait and retire repeat, in that order, once per step call.  The step
 programs carry ``jax.named_scope``s in their op metadata: ``embed``,
-then per layer ``qkv``, ``kv_write``, ``kv_read``, ``attn_kernel``,
-``attn_out`` and ``mlp``, then ``head_sample``.
+then per layer ``qkv``, ``kv_write``, ``attn_kernel`` (which reads the
+layer's pages from the pool), ``attn_out`` and ``mlp``, then
+``head_sample``.
 """
 from __future__ import annotations
 

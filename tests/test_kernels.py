@@ -136,16 +136,17 @@ def _window_case(seed, B, C, H, Hkv, D, P, slots, dtype=jnp.float32,
         start = rng.randint(0, max(P * slots - C, 0) + 1, B)
     if n_tok is None:
         n_tok = rng.randint(0, C + 1, B)
-    return (q, kp, vp, bt, jnp.asarray(start, jnp.int32),
+    pool = jnp.stack([kp, vp], axis=1)[:, :, None]   # one layer
+    return (q, pool, bt, jnp.asarray(start, jnp.int32),
             jnp.asarray(n_tok, jnp.int32))
 
 
 def _assert_window_parity(case, dtype=jnp.float32, block_q=None,
                           msg=""):
-    q, kp, vp, bt, start, n_tok = case
-    out = pa.paged_prefill_attention(q, kp, vp, bt, start, n_tok,
+    q, pool, bt, start, n_tok = case
+    out = pa.paged_prefill_attention(q, pool, 0, bt, start, n_tok,
                                      block_q=block_q, interpret=True)
-    ref_out = pa.paged_prefill_attention_ref(q, kp, vp, bt, start, n_tok)
+    ref_out = pa.paged_prefill_attention_ref(q, pool, 0, bt, start, n_tok)
     tol = 1e-5 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref_out, np.float32),
@@ -235,12 +236,12 @@ def test_prefill_window_choose_block_dispatch():
 
 def test_prefill_window_unknown_impl_raises():
     case = _window_case(90, B=1, C=4, H=4, Hkv=2, D=16, P=8, slots=2)
-    q, kp, vp, bt, start, n_tok = case
+    q, pool, bt, start, n_tok = case
     with pytest.raises(ValueError, match="paged_prefill_attention"):
-        ops.paged_prefill_attention(q, kp, vp, bt, start, n_tok,
+        ops.paged_prefill_attention(q, pool, 0, bt, start, n_tok,
                                     impl="nope")
     with pytest.raises(ValueError, match="paged_attention"):
-        ops.paged_attention(q[:, 0], kp, vp, bt,
+        ops.paged_attention(q[:, 0], pool, 0, bt,
                             jnp.asarray([1], jnp.int32), impl="nope")
     assert "kernel" in ops.PAGED_PREFILL_IMPLS
     assert "ref" in ops.PAGED_PREFILL_IMPLS
@@ -250,10 +251,10 @@ def test_prefill_window_ops_kernel_route():
     """ops.paged_prefill_attention(impl='kernel') actually reaches the
     grid kernel and matches the ref route at 1e-5."""
     case = _window_case(91, B=3, C=8, H=4, Hkv=2, D=16, P=8, slots=3)
-    q, kp, vp, bt, start, n_tok = case
-    k_out = ops.paged_prefill_attention(q, kp, vp, bt, start, n_tok,
+    q, pool, bt, start, n_tok = case
+    k_out = ops.paged_prefill_attention(q, pool, 0, bt, start, n_tok,
                                         impl="kernel")
-    r_out = ops.paged_prefill_attention(q, kp, vp, bt, start, n_tok,
+    r_out = ops.paged_prefill_attention(q, pool, 0, bt, start, n_tok,
                                         impl="ref")
     np.testing.assert_allclose(np.asarray(k_out), np.asarray(r_out),
                                atol=1e-5, rtol=1e-5)

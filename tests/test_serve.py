@@ -15,7 +15,9 @@ from repro import configs, serve
 from repro.core import CommQueue, LocalTransport, SymmetricHeap
 from repro.kernels import ops
 from repro.kernels.paged_attention import (paged_decode_attention,
-                                           paged_decode_attention_ref)
+                                           paged_decode_attention_ref,
+                                           paged_prefill_attention,
+                                           paged_prefill_attention_ref)
 from repro.models import registry
 from repro.parallel.ctx import ParallelCtx
 from repro.serve import (NULL_PAGE, FCFSScheduler, PagedKVCache,
@@ -302,6 +304,12 @@ def test_preempted_request_eventually_completes():
 # ======================================================================
 # paged attention parity (the tier-1 acceptance bar)
 # ======================================================================
+def _pool(kp, vp):
+    """The engine's pool layout ``(n_pages, 2, L, P, H_kv, D)`` holding
+    ``kp`` / ``vp`` as its one layer."""
+    return jnp.stack([jnp.asarray(kp), jnp.asarray(vp)], axis=1)[:, :, None]
+
+
 def _paged_case(seed=0, B=3, H=4, Hkv=2, D=16, P=4, n_pages=10, slots=3):
     rng = np.random.RandomState(seed)
     q = jnp.asarray(rng.randn(B, H, D).astype(np.float32))
@@ -315,8 +323,9 @@ def _paged_case(seed=0, B=3, H=4, Hkv=2, D=16, P=4, n_pages=10, slots=3):
 
 def test_paged_attention_kernel_matches_ref():
     q, kp, vp, bt, lens = _paged_case()
-    ref = paged_decode_attention_ref(q, kp, vp, bt, lens)
-    ker = paged_decode_attention(q, kp, vp, bt, lens, interpret=True)
+    pool = _pool(kp, vp)
+    ref = paged_decode_attention_ref(q, pool, 0, bt, lens)
+    ker = paged_decode_attention(q, pool, 0, bt, lens, interpret=True)
     np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
                                atol=1e-6, rtol=1e-6)
     # inactive sequence (len 0) -> exactly zero output
@@ -328,7 +337,7 @@ def test_paged_attention_matches_contiguous_ops_attention():
     to contiguous ops.attention on the same sequences."""
     q, kp, vp, bt, lens = _paged_case()
     for impl in ("kernel", "ref"):
-        out = ops.paged_attention(q, kp, vp, bt, lens, impl=impl)
+        out = ops.paged_attention(q, _pool(kp, vp), 0, bt, lens, impl=impl)
         for b in range(q.shape[0]):
             L = int(lens[b])
             if L == 0:
@@ -363,7 +372,7 @@ def test_paged_attention_full_final_page():
     bt = jnp.asarray(bt)
     lens = jnp.asarray([2 * P, 3 * P, 6 * P], np.int32)  # all full pages
     for impl in ("kernel", "ref"):
-        out = ops.paged_attention(q, kp, vp, bt, lens, impl=impl)
+        out = ops.paged_attention(q, _pool(kp, vp), 0, bt, lens, impl=impl)
         for b in range(B):
             L = int(lens[b])
             kc = kp[bt[b]].reshape(-1, Hkv, D)[:L]
@@ -391,8 +400,7 @@ def test_paged_attention_first_decode_after_midpage_prefill():
     for L in (5, 6, 7):          # prompt ended mid-page at L-1
         lens = jnp.asarray([L + 1], np.int32)    # after this write
         for impl in ("kernel", "ref"):
-            out = ops.paged_attention(q, jnp.asarray(kp),
-                                      jnp.asarray(vp), bt, lens,
+            out = ops.paged_attention(q, _pool(kp, vp), 0, bt, lens,
                                       impl=impl)
             kc = kp[np.asarray(bt[0])].reshape(-1, Hkv, D)[:L + 1]
             vc = vp[np.asarray(bt[0])].reshape(-1, Hkv, D)[:L + 1]
@@ -418,7 +426,8 @@ def test_paged_prefill_window_matches_per_position_decode():
                      jnp.int32)
     start = jnp.asarray([0, 4, 2], jnp.int32)   # mid-page + page starts
     n_tok = jnp.asarray([4, 3, 0], np.int32)    # full, padded, inactive
-    out = ops.paged_prefill_attention(q, kp, vp, bt, start, n_tok)
+    pool = _pool(kp, vp)
+    out = ops.paged_prefill_attention(q, pool, 0, bt, start, n_tok)
     for b in range(B):
         for j in range(C):
             if j >= int(n_tok[b]):
@@ -426,7 +435,7 @@ def test_paged_prefill_window_matches_per_position_decode():
                 continue
             lens = np.zeros(B, np.int32)
             lens[b] = int(start[b]) + j + 1
-            ref = paged_decode_attention_ref(q[:, j], kp, vp, bt,
+            ref = paged_decode_attention_ref(q[:, j], pool, 0, bt,
                                              jnp.asarray(lens))
             np.testing.assert_allclose(
                 np.asarray(out[b, j]), np.asarray(ref[b]),
@@ -437,11 +446,56 @@ def test_paged_attention_gqa_and_mqa_groups():
     for H, Hkv in ((4, 1), (6, 2), (4, 4)):
         q, kp, vp, bt, lens = _paged_case(seed=H * 10 + Hkv, H=H,
                                           Hkv=Hkv)
-        ref = paged_decode_attention_ref(q, kp, vp, bt, lens)
-        ker = paged_decode_attention(q, kp, vp, bt, lens, interpret=True)
+        pool = _pool(kp, vp)
+        ref = paged_decode_attention_ref(q, pool, 0, bt, lens)
+        ker = paged_decode_attention(q, pool, 0, bt, lens, interpret=True)
         np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
                                    atol=1e-6, rtol=1e-6,
                                    err_msg=f"H={H} Hkv={Hkv}")
+
+
+N_LAYERS = 4
+
+
+@pytest.mark.parametrize("layer", [0, N_LAYERS // 2, N_LAYERS - 1],
+                         ids=["first", "middle", "last"])
+@pytest.mark.parametrize("H,Hkv", [(6, 2), (4, 1)], ids=["gqa", "mqa"])
+@pytest.mark.parametrize("kind", ["decode", "window"])
+def test_paged_kernels_read_the_layer_from_the_whole_pool(kind, H, Hkv,
+                                                          layer):
+    """Given the whole multi-layer pool and a layer index, each kernel
+    gives, bit for bit, what it gives on the one-layer pool
+    ``pool[:, :, layer:layer + 1]`` at layer 0, and matches its oracle;
+    its answer depends on that layer's pages alone."""
+    rng = np.random.RandomState(100 + 10 * H + Hkv)
+    B, C, D, P, n_pages, slots = 3, 4, 16, 4, 10, 3
+    pool = jnp.asarray(
+        rng.randn(n_pages, 2, N_LAYERS, P, Hkv, D).astype(np.float32))
+    bt = jnp.asarray(rng.permutation(np.arange(1, 10))
+                     .reshape(B, slots).astype(np.int32))
+    if kind == "decode":
+        q = jnp.asarray(rng.randn(B, H, D).astype(np.float32))
+        rest = (jnp.asarray([P * slots, 5, 0], jnp.int32),)
+        kernel, oracle, tol = paged_decode_attention, \
+            paged_decode_attention_ref, 1e-6
+    else:
+        q = jnp.asarray(rng.randn(B, C, H, D).astype(np.float32))
+        rest = (jnp.asarray([0, 5, 8], jnp.int32),     # page start,
+                jnp.asarray([4, 3, 0], jnp.int32))     # mid-page, idle
+        kernel, oracle, tol = paged_prefill_attention, \
+            paged_prefill_attention_ref, 1e-5
+    got = kernel(q, pool, jnp.int32(layer), bt, *rest, interpret=True)
+    one = kernel(q, pool[:, :, layer:layer + 1], jnp.int32(0), bt, *rest,
+                 interpret=True)
+    assert np.array_equal(np.asarray(got), np.asarray(one))
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(oracle(q, pool, layer, bt, *rest)),
+        atol=tol, rtol=tol)
+    # the other layers' pages are never read
+    other = pool.at[:, :, np.arange(N_LAYERS) != layer].set(jnp.nan)
+    assert np.array_equal(
+        np.asarray(kernel(q, other, jnp.int32(layer), bt, *rest,
+                          interpret=True)), np.asarray(got))
 
 
 # ======================================================================

@@ -21,8 +21,8 @@ from repro.serve import Request, SamplingParams, ServeConfig, ServeEngine
 
 STEP_SPANS = ["serve.prepare", "serve.dispatch", "serve.wait",
               "serve.retire"]
-SCOPES = ["embed", "qkv", "kv_write", "kv_read", "attn_kernel", "attn_out",
-          "mlp", "head_sample"]
+SCOPES = ["embed", "qkv", "kv_write", "attn_kernel", "attn_out", "mlp",
+          "head_sample"]
 
 
 @pytest.fixture(scope="module")
@@ -234,8 +234,24 @@ def test_scopes_change_only_the_metadata(model, monkeypatch, make, window):
     names = set(re.findall(r'op_name="([^"]*)"', a))
     for scope in SCOPES:
         assert any(f"/{scope}/" in n for n in names), scope
-    kv = [n for n in names if "/kv_write/" in n or "/kv_read/" in n]
+    kv = [n for n in names if "/kv_write/" in n]
     assert kv and all("/while/body/" in n for n in kv)  # in the layer scan
+
+
+@pytest.mark.parametrize("make,window", [
+    (serve.make_decode_step, False), (serve.make_prefill, True),
+    (serve.make_verify, True)], ids=["decode", "prefill", "verify"])
+def test_no_step_slices_a_pool_half(model, make, window):
+    # the kernels take the whole pool and a layer index: no instruction
+    # of the step makes a K or V half of the pool, (n_pages, 1, L, P,
+    # kvh, dh) or (n_pages, L, P, kvh, dh), to take its layer from
+    lowered = _lowered(model, make, window)
+    n_pages, _, n_layers, page, kvh, dh = lowered.args_info[0][1].shape
+    half = re.compile(r"\[%d,(?:1,)?%d,%d,%d,%d\]"
+                      % (n_pages, n_layers, page, kvh, dh))
+    hlo = lowered.as_text(dialect="hlo")
+    assert f"[{n_pages},2,{n_layers},{page},{kvh},{dh}]" in hlo
+    assert not half.findall(hlo)
 
 
 SCOPE_KEY = textwrap.dedent("""

@@ -39,11 +39,13 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-# (name, query heads, KV heads, head_dim, pages) per chip: gemma-2b on
-# one chip; qwen3-8b's per-rank share at TP 4 (32/4 heads, 8/4 KV heads)
-SHAPES = [("gemma-2b", 8, 1, 256, 2048), ("qwen3-8b-tp4-rank", 8, 2, 128,
-                                          1024)]
+# (name, query heads, KV heads, head_dim, pages) per chip: gemma-2b and
+# qwen3-8b on one chip; qwen3-8b's per-rank share at TP 4 (32/4 heads,
+# 8/4 KV heads)
+SHAPES = [("gemma-2b", 8, 1, 256, 2048), ("qwen3-8b", 32, 8, 128, 2048),
+          ("qwen3-8b-tp4-rank", 8, 2, 128, 1024)]
 PAGE_TOKENS, BATCH, WINDOW, MAX_SEQ = 16, 8, 256, 4096
+LAYERS = 16             # the kernels read one layer of the whole pool
 
 
 def _structs(sharding, **shapes):
@@ -61,13 +63,14 @@ def test_paged_decode_kernel_compiles(one_chip, name, h, hkv, d, n_pages):
     slots = MAX_SEQ // PAGE_TOKENS
     a = _structs(one_chip,
                  q=((BATCH, h, d), jnp.bfloat16),
-                 k=((n_pages, PAGE_TOKENS, hkv, d), jnp.bfloat16),
-                 v=((n_pages, PAGE_TOKENS, hkv, d), jnp.bfloat16),
+                 pool=((n_pages, 2, LAYERS, PAGE_TOKENS, hkv, d),
+                       jnp.bfloat16),
+                 layer=((), jnp.int32),
                  bt=((BATCH, slots), jnp.int32),
                  lens=((BATCH,), jnp.int32))
-    fn = jax.jit(lambda q, k, v, bt, lens: pa.paged_decode_attention(
-        q, k, v, bt, lens, interpret=False))
-    _assert_kernel(fn.lower(a["q"], a["k"], a["v"], a["bt"],
+    fn = jax.jit(lambda q, pool, li, bt, lens: pa.paged_decode_attention(
+        q, pool, li, bt, lens, interpret=False))
+    _assert_kernel(fn.lower(a["q"], a["pool"], a["layer"], a["bt"],
                             a["lens"]).compile())
 
 
@@ -77,15 +80,16 @@ def test_paged_prefill_kernel_compiles(one_chip, name, h, hkv, d, n_pages):
     slots = MAX_SEQ // PAGE_TOKENS
     a = _structs(one_chip,
                  q=((BATCH, WINDOW, h, d), jnp.bfloat16),
-                 k=((n_pages, PAGE_TOKENS, hkv, d), jnp.bfloat16),
-                 v=((n_pages, PAGE_TOKENS, hkv, d), jnp.bfloat16),
+                 pool=((n_pages, 2, LAYERS, PAGE_TOKENS, hkv, d),
+                       jnp.bfloat16),
+                 layer=((), jnp.int32),
                  bt=((BATCH, slots), jnp.int32),
                  start=((BATCH,), jnp.int32),
                  n_tok=((BATCH,), jnp.int32))
-    fn = jax.jit(lambda q, k, v, bt, s, n: pa.paged_prefill_attention(
-        q, k, v, bt, s, n, interpret=False))
-    _assert_kernel(fn.lower(a["q"], a["k"], a["v"], a["bt"], a["start"],
-                            a["n_tok"]).compile())
+    fn = jax.jit(lambda q, pool, li, bt, s, n: pa.paged_prefill_attention(
+        q, pool, li, bt, s, n, interpret=False))
+    _assert_kernel(fn.lower(a["q"], a["pool"], a["layer"], a["bt"],
+                            a["start"], a["n_tok"]).compile())
 
 
 def test_gemma_2b_decode_step_compiles(one_chip, monkeypatch):
