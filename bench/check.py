@@ -4,8 +4,8 @@ Once the window has closed and the program's state is freed, a sample
 of the greedy requests that were served tokens (finished, or in flight
 at the close: every token they hold came from the timed path), drawn
 from the seed and always holding the one with the most served tokens,
-is run through the
-plain reference (``bench/reference.py``) over its prompt and its served
+is run through the plain reference of the configuration's family
+(``bench/families/<family>.py``) over its prompt and its served
 tokens.  For every served token the gap is how far its reference logit
 lies below the reference's best logit at that position; the number
 compared is the widest gap.  A greedy server that computes what the
@@ -21,8 +21,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from . import traffic
-from .reference import Reference
+from . import families, traffic
 
 _SAMPLE_STREAM = 4
 
@@ -52,11 +51,11 @@ def _inputs(chosen):
     return seqs, rows, np.asarray(toks, np.int64)
 
 
-def gaps(model: dict, seed: int, chosen: list, control: bool = False):
+def gaps(root, conf: dict, seed: int, chosen: list, control: bool = False):
     """Per served token: the reference's best logit minus the logit of
     the token served (``control=False``) or of the token the float8
     control ranks first (``control=True``)."""
-    ref = Reference(model, seed)
+    ref = families.load(root, conf).Reference(conf, seed)
     seqs, rows, toks = _inputs(chosen)
     hs = ref.hidden(seqs, rows)
     if not control:
@@ -68,13 +67,13 @@ def gaps(model: dict, seed: int, chosen: list, control: bool = False):
     return best - got
 
 
-def verdict(model: dict, seed: int, done: list, limits: dict,
+def verdict(root, conf: dict, seed: int, done: list, limits: dict,
             control: bool = False) -> tuple:
     """(correct, checks): the numbers compared, each with its limit.
     ``control=True`` judges the float8 control's tokens in place of
     the served ones."""
     chosen = sample(done, int(limits["sample_requests"]), seed)
-    g = gaps(model, seed, chosen, control) if chosen else np.zeros(0)
+    g = gaps(root, conf, seed, chosen, control) if chosen else np.zeros(0)
     n = int(g.size)
     widest = float(g.max()) if n else float("inf")
     checks = {

@@ -36,7 +36,7 @@ def readings(root, workload, seed, seconds, **kw) -> dict:
     conf = sv["cell"]["conf"]
     out = {"seed": seed}
     for kind, ctl in (("sound", False), ("control", True)):
-        ok, checks = check.verdict(conf["model"], seed, sv["served"],
+        ok, checks = check.verdict(root, conf, seed, sv["served"],
                                    conf["correct"], control=ctl)
         gap = checks["max_logit_gap"]["value"]
         out[kind] = gap if gap != float("inf") else None

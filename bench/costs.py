@@ -57,22 +57,3 @@ def prefill_attention(start, n_tok, h: int, hkv: int, dh: int,
     nbytes = (2 * hkv * dh * kv_bytes * int((s + n).sum())
               + 2 * int(n.sum()) * h * dh * io_bytes)
     return float(flops), float(nbytes)
-
-
-def layer_matmul_params(m: dict) -> int:
-    """Weights one token multiplies in one decoder layer (whole model,
-    all chips): q, k, v, o and the feed-forward."""
-    d, h, hkv, dh, ff = m["d"], m["h"], m["hkv"], m["dh"], m["ff"]
-    glu = 3 if m["act"] == "silu" else 2
-    return d * (h + 2 * hkv) * dh + h * dh * d + glu * d * ff
-
-
-def model_flops(m: dict, new_tokens: int, attended: int,
-                logit_rows: int) -> float:
-    """Model FLOPs (whole model, all chips) of processing ``new_tokens``
-    tokens that together attend to ``attended`` cached positions in
-    each layer, and of ``logit_rows`` rows of the output head."""
-    L = m["layers"]
-    return float(2 * new_tokens * layer_matmul_params(m) * L
-                 + 4 * attended * m["h"] * m["dh"] * L
-                 + 2 * logit_rows * m["d"] * m["vocab"])

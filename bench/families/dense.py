@@ -1,10 +1,13 @@
-"""The plain reference: a dense decoder's forward pass in float32 at
-``Precision.HIGHEST``, written from the configuration alone.
+"""The dense decoder family: its plain reference, a forward pass in
+float32 at ``Precision.HIGHEST`` written from the configuration alone;
+its parameter layout; its work count; and its mapping onto the
+program's ``ArchConfig``.
 
-It imports nothing of the program and takes nothing the program made:
-it knows the parameter layout from the configuration and makes each
-layer's weights again from the seed (``bench/weights.py``), one layer
-at a time, so it fits on the chip after the program's state is freed.
+The reference imports nothing of the program and takes nothing the
+program made: it knows the parameter layout from the configuration and
+makes each layer's weights again from the seed (``bench/weights.py``),
+one layer at a time, so it fits on the chip after the program's state
+is freed.
 Each sequence is processed whole (no cache, no batching, no kernels);
 attention is causal softmax over all earlier positions, computed in
 blocks of query rows.
@@ -13,9 +16,13 @@ blocks of query rows.
 rounded to float8 e4m3 (weights per output column, activations per
 row, q/k/v per head vector, each with its own scale) with float32
 accumulation -- the step below the bfloat16 the configurations state.
+
+Only ``arch_config`` imports the program, inside the function; the
+harness alone calls it.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 
@@ -23,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import weights
+from bench import weights
 
 HI = jax.lax.Precision.HIGHEST
 Q_BLOCK = 1024            # query rows per attention block
@@ -33,8 +40,9 @@ ROW_PAD = 256             # scored rows are padded to a multiple
 ACTS = ("silu", "relu2")
 
 
-def dims(model: dict) -> dict:
+def dims(conf: dict) -> dict:
     """The sizes the forward pass needs, from the configuration's keys."""
+    model = conf["model"]
     d = {
         "d": model["hidden_size"], "h": model["num_attention_heads"],
         "hkv": model["num_key_value_heads"], "dh": model["head_dim"],
@@ -50,10 +58,10 @@ def dims(model: dict) -> dict:
     return d
 
 
-def layout(model: dict, tp: int = 1, dtype=jnp.bfloat16) -> dict:
+def layout(conf: dict, tp: int = 1, dtype=jnp.bfloat16) -> dict:
     """The parameter tree the program is expected to hold on each of
     ``tp`` chips' worth of a layer, as global ShapeDtypeStructs."""
-    m = dims(model)
+    m = dims(conf)
     d, h, hkv, dh, ff, L = (m["d"], m["h"], m["hkv"], m["dh"], m["ff"],
                             m["layers"])
     vp = -(-m["vocab"] // tp) * tp
@@ -156,10 +164,10 @@ def _head_block(hs, table, fp8):
 class Reference:
     """The reference model of one configuration under one seed."""
 
-    def __init__(self, model: dict, seed: int):
-        self.model, self.seed = model, int(seed)
-        self.m = dims(model)
-        self.tree = layout(model)
+    def __init__(self, conf: dict, seed: int):
+        self.conf, self.seed = conf, int(seed)
+        self.m = dims(conf)
+        self.tree = layout(conf)
         self._key = tuple(sorted(self.m.items()))
 
     def _top(self, path):
@@ -215,3 +223,43 @@ class Reference:
                 picked = jnp.where(inb, got, picked)
         return (np.asarray(best)[:n], np.asarray(arg)[:n],
                 None if tok is None else np.asarray(picked)[:n])
+
+
+# ----------------------------------------------------------------------
+def layer_matmul_params(m: dict) -> int:
+    """Weights one token multiplies in one decoder layer (whole model,
+    all chips): q, k, v, o and the feed-forward."""
+    d, h, hkv, dh, ff = m["d"], m["h"], m["hkv"], m["dh"], m["ff"]
+    glu = 3 if m["act"] == "silu" else 2
+    return d * (h + 2 * hkv) * dh + h * dh * d + glu * d * ff
+
+
+def model_flops(m: dict, new_tokens: int, attended: int,
+                logit_rows: int) -> float:
+    """Model FLOPs (whole model, all chips) of processing ``new_tokens``
+    tokens that together attend to ``attended`` cached positions in
+    each layer, and of ``logit_rows`` rows of the output head."""
+    L = m["layers"]
+    return float(2 * new_tokens * layer_matmul_params(m) * L
+                 + 4 * attended * m["h"] * m["dh"] * L
+                 + 2 * logit_rows * m["d"] * m["vocab"])
+
+
+ACT = {"silu": "swiglu", "relu2": "relu2"}
+
+
+def arch_config(conf: dict):
+    """The program's ArchConfig for the configuration file: the
+    registered architecture with every size the file states."""
+    from repro import configs
+    m = conf["model"]
+    base = configs.get(conf["arch"])
+    return dataclasses.replace(
+        base, name=conf["name"], n_layers=m["num_hidden_layers"],
+        d_model=m["hidden_size"], n_heads=m["num_attention_heads"],
+        n_kv=m["num_key_value_heads"], head_dim=m["head_dim"],
+        d_ff=m["intermediate_size"], vocab=m["vocab_size"],
+        act=ACT[m["hidden_act"]], qk_norm=bool(m["qk_norm"]),
+        rope_theta=float(m["rope_theta"]),
+        tie_embeddings=bool(m["tie_word_embeddings"]),
+        norm_eps=float(m.get("rms_norm_eps", m.get("norm_eps"))))

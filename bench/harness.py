@@ -3,10 +3,12 @@ program's normal path, warms the cell's own shapes, drives
 ``ServeEngine.tick`` for a fixed window, reads the metrics the cell
 lists and decides ``correct`` against the plain reference.
 
-Everything that belongs to one configuration, traffic mix or metric
-is a file found by name: ``bench/configs/<config>.json``,
-``bench/traffic/<mix>.json``, ``bench/metrics/<base>.py`` (``base`` is
-a metric's name up to its first ``.``).
+Everything that belongs to one configuration, architecture family,
+traffic mix or metric is a file found by name:
+``bench/configs/<config>.json``, ``bench/families/<family>.py`` (the
+configuration's ``family``), ``bench/traffic/<mix>.json``,
+``bench/metrics/<base>.py`` (``base`` is a metric's name up to its
+first ``.``).
 """
 from __future__ import annotations
 
@@ -23,8 +25,7 @@ from pathlib import Path
 
 import numpy as np
 
-from . import check, costs, trace_reduce, traffic
-from .reference import dims, layout
+from . import check, costs, families, trace_reduce, traffic
 
 TRACE_S = 10.0            # device trace: the first seconds of the window
 STEADY_LIMIT_S = 240.0    # longest a steady set may take to prefill
@@ -53,26 +54,6 @@ def load_cell(root: Path, workload: str, bench: dict | None = None) -> dict:
     return {"root": root, "workload": wl, "conf": conf, "mix": mix,
             "end_to_end": [m for m in bench["end_to_end"] if mine(m)],
             "per_layer": [m for m in bench["per_layer"] if mine(m)]}
-
-
-ACT = {"silu": "swiglu", "relu2": "relu2"}
-
-
-def arch_config(conf: dict):
-    """The program's ArchConfig for the configuration file: the
-    registered architecture with every size the file states."""
-    from repro import configs
-    m = conf["model"]
-    base = configs.get(conf["arch"])
-    return dataclasses.replace(
-        base, name=conf["name"], n_layers=m["num_hidden_layers"],
-        d_model=m["hidden_size"], n_heads=m["num_attention_heads"],
-        n_kv=m["num_key_value_heads"], head_dim=m["head_dim"],
-        d_ff=m["intermediate_size"], vocab=m["vocab_size"],
-        act=ACT[m["hidden_act"]], qk_norm=bool(m["qk_norm"]),
-        rope_theta=float(m["rope_theta"]),
-        tie_embeddings=bool(m["tie_word_embeddings"]),
-        norm_eps=float(m.get("rms_norm_eps", m.get("norm_eps"))))
 
 
 def _sample_seed(seed: int) -> int:
@@ -124,18 +105,20 @@ def build(cell: dict, seed: int, devices, wrap=None):
     conf, s = cell["conf"], cell["conf"]["serve"]
     tp = int(conf["tp"])
     dtype = jnp.dtype(conf["dtype"])
-    cfg = arch_config(conf)
+    family = families.load(cell["root"], conf)
+    cfg = family.arch_config(conf)
     ctx = ParallelCtx(dp_size=1, tp_size=tp, sp=False, remat=False,
                       backend=conf["comm_backend"], param_dtype=dtype,
                       compute_dtype=dtype)
     api = registry.build(cfg)
-    want = layout(conf["model"], tp, dtype)
+    want = family.layout(conf, tp, dtype)
     have = jax.eval_shape(lambda k: api.init(k, cfg, ctx.with_(tp_size=1)),
                           jax.random.PRNGKey(0))
     if jax.tree.map(lambda a: (a.shape, a.dtype), have) != \
             jax.tree.map(lambda a: (a.shape, a.dtype), want):
-        raise RuntimeError("the program's parameter layout differs from "
-                           "the one the reference regenerates")
+        raise RuntimeError(
+            f"the program's parameter layout differs from the one "
+            f"{families.path(cell['root'], conf)} regenerates")
     scfg = serve.ServeConfig(
         page_tokens=s["page_tokens"], n_pages=s["n_pages"],
         max_batch=s["max_batch"], max_seq=s["max_seq"],
@@ -349,7 +332,8 @@ class Run:
     def __init__(self, cell, meta, window, calls, setup_s, red, peak,
                  chips):
         self.cell, self.meta = cell, meta
-        self.model = dims(cell["conf"]["model"])
+        self.family = families.load(cell["root"], cell["conf"])
+        self.model = self.family.dims(cell["conf"])
         self.tp = meta["tp"]
         self.chips = chips
         self.seconds = window["W1"] - window["W0"]
@@ -463,7 +447,7 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
     metrics = read_metrics(runv, cell["per_layer" if trace
                                        else "end_to_end"])
     t_ref = clock()
-    ok, checks = check.verdict(cell["conf"]["model"], seed, sv["served"],
+    ok, checks = check.verdict(cell["root"], cell["conf"], seed, sv["served"],
                                cell["conf"]["correct"])
     print(f"reference check: {clock() - t_ref:.1f} s", file=sys.stderr)
     attempted = (len(window["recs"]) if window["closed"] else

@@ -2,10 +2,8 @@
 the prefill and decode calls processed in the traced window (real
 lengths only; padding rows and empty slots are no work), over the
 traced window times the chips times the peak bf16 FLOP/s, in
-percent."""
+percent.  The configuration's family counts the FLOPs."""
 import numpy as np
-
-from bench import costs
 
 
 def read(run, name):
@@ -27,6 +25,6 @@ def read(run, name):
                 for r in run.recs.values())
     if tokens == 0:
         return None
-    fl = costs.model_flops(run.model, tokens, attended, rows)
+    fl = run.family.model_flops(run.model, tokens, attended, rows)
     window = run.trace["window_ns"] / 1e9
     return 100.0 * fl / (window * run.chips * run.peak["bf16_flops_per_s"])

@@ -1,6 +1,7 @@
 """Shared by the benchmark's CPU tests: a checkout-shaped directory
 holding a toy configuration and toy mixes, with the real metric
-readers, so the harness runs end to end on the CPU in seconds."""
+readers and a copy of the real architecture families, so the harness
+runs end to end on the CPU in seconds."""
 from __future__ import annotations
 
 import json
@@ -23,12 +24,15 @@ CELLS = {"tiny.open": ("tiny", "tiny_open"),
 
 def make_root(tmp: Path) -> Path:
     """A directory laid out like a checkout: BENCHMARK.json naming the
-    toy cells, their files under bench/, and bench/metrics linked to
-    the real readers."""
+    toy cells, their files under bench/, bench/metrics linked to the
+    real readers and bench/families a copy of the real families, to
+    which a test may add its own."""
     root = tmp / "root"
     (root / "bench" / "configs").mkdir(parents=True)
     (root / "bench" / "traffic").mkdir()
     os.symlink(REPO / "bench" / "metrics", root / "bench" / "metrics")
+    shutil.copytree(REPO / "bench" / "families", root / "bench" / "families",
+                    ignore=shutil.ignore_patterns("__pycache__"))
     for name in ("tiny", "tiny16"):
         shutil.copy(DATA / f"{name}.json", root / "bench" / "configs")
     for mix in ("tiny_open", "tiny_closed", "tiny_steady"):

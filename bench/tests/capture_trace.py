@@ -17,7 +17,7 @@ REPO = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(REPO), str(REPO / "src")]
 
 CONF = {
-    "name": "toy128", "arch": "qwen3-8b",
+    "name": "toy128", "arch": "qwen3-8b", "family": "dense",
     "model": {
         "hidden_size": 512, "intermediate_size": 1024,
         "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 128,
@@ -37,7 +37,7 @@ def main(out: str) -> int:
     from bench import harness
     from repro import serve
     devs = harness.devices_for(1, require_chip=True)
-    eng, _ = harness.build({"conf": CONF}, 1, devs)
+    eng, _ = harness.build({"conf": CONF, "root": REPO}, 1, devs)
     harness.warm(eng)
     for rid in range(2):
         eng.submit(serve.Request(rid=rid, prompt=list(range(7, 107 + rid)),

@@ -1,10 +1,11 @@
-"""Work counted from shapes and real lengths, checked by hand; the
-peaks table."""
+"""Work counted from shapes and real lengths, checked by hand: the
+kernels' counts and the dense family's model FLOPs; the peaks table."""
 import pytest
 
 import bench_tiny  # noqa: F401  (puts the repo on sys.path)
 
 from bench import costs
+from bench.families import dense
 
 V5E = "TPU v5 lite"
 
@@ -42,11 +43,11 @@ def test_model_flops_by_hand():
     m = {"d": 8, "h": 2, "hkv": 1, "dh": 4, "ff": 16, "vocab": 10,
          "layers": 3, "act": "silu"}
     per_layer = 8 * (2 + 2) * 4 + 2 * 4 * 8 + 3 * 8 * 16
-    assert costs.layer_matmul_params(m) == per_layer
-    assert costs.model_flops(m, new_tokens=5, attended=9, logit_rows=2) \
+    assert dense.layer_matmul_params(m) == per_layer
+    assert dense.model_flops(m, new_tokens=5, attended=9, logit_rows=2) \
         == 2 * 5 * per_layer * 3 + 4 * 9 * 2 * 4 * 3 + 2 * 2 * 8 * 10
     relu2 = dict(m, act="relu2")
-    assert costs.layer_matmul_params(relu2) == per_layer - 8 * 16
+    assert dense.layer_matmul_params(relu2) == per_layer - 8 * 16
 
 
 def test_roofline_takes_the_larger_bound():

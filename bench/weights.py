@@ -4,8 +4,8 @@ they are served in.
 Every leaf is drawn from integer random bits (``jax.random.bits``,
 uint16) mapped exactly to a uniform on (-1, 1) and scaled once, so the
 values do not depend on how XLA fuses the call: the reference
-(``bench/reference.py``) makes any one layer again from the same seed
-and gets the same numbers.  Matrices have standard deviation
+(``bench/families/<family>.py``) makes any one layer again from the
+same seed and gets the same numbers.  Matrices have standard deviation
 1/sqrt(fan_in), the embedding and head tables 0.02, norm scales are
 1 +- 0.2.  The leaves' names, shapes and dtypes are the program's
 parameter layout, which the harness checks against the program's own
